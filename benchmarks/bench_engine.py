@@ -88,6 +88,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import init_params
 from repro.serving.engine import Engine, EngineConfig
 from repro.serving.request import Request, make_synthetic_request
@@ -1112,6 +1113,7 @@ def check_regression(decode: dict, preempt: dict, http: dict,
 
 
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small/fast variant for CI (decode + prefill "

@@ -68,6 +68,31 @@ PLATFORMS: Dict[str, Platform] = {
 }
 
 
+# ``device_kind`` as JAX reports it -> the PLATFORMS entry that prices
+# Algorithm 1 when no platform is named.  A device that is not listed
+# is an error, never a default.  The CPU backend (tests, simulations on
+# a host without an accelerator) carries the paper's A10 calibration.
+DEVICE_PLATFORMS: Dict[str, str] = {
+    "TPU v5 lite": "v5e",
+    "cpu": "a10",
+}
+
+
+def device_platform(device: Any = None) -> str:
+    """The PLATFORMS key for ``device`` (default: ``jax.devices()[0]``),
+    looked up by its ``device_kind``; raises for an unlisted kind."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    kind = device.device_kind
+    if kind not in DEVICE_PLATFORMS:
+        raise ValueError(
+            f"no analytic platform for device kind {kind!r} "
+            f"({device.platform}); add it to DEVICE_PLATFORMS or name a "
+            f"platform explicitly (one of {sorted(PLATFORMS)})")
+    return DEVICE_PLATFORMS[kind]
+
+
 # ---------------------------------------------------------------------------
 # Analytic model costs
 # ---------------------------------------------------------------------------
@@ -502,6 +527,7 @@ class PerfModelProvider:
     interface the scheduler consumes (paper §3.1 made configurable):
 
       * ``"analytic"``            — analytic calibration for ``platform``
+        (None = the attached device's, via ``device_platform``)
       * ``"analytic:<platform>"`` — analytic calibration for a named platform
       * ``"measured"``            — run ``OfflineProfiler`` on the current
         backends (cached to ``profile_cache`` when given; an existing
@@ -510,7 +536,7 @@ class PerfModelProvider:
     """
 
     cfg: ModelConfig
-    platform: str = "a10"
+    platform: Optional[str] = None
     profile_cache: Optional[str] = None
     profile_grid: Optional[Dict[str, Tuple[int, ...]]] = None
     host_kv_dtype: str = "fp32"
@@ -518,7 +544,7 @@ class PerfModelProvider:
     def resolve(self, spec: str):
         spec = (spec or "analytic").strip()
         if spec == "analytic":
-            return self._analytic(self.platform)
+            return self._analytic(self.platform or device_platform())
         if spec.startswith("analytic:"):
             return self._analytic(spec.split(":", 1)[1])
         if spec.startswith("file:"):
@@ -571,7 +597,8 @@ class PerfModelProvider:
         return model.profile_grid == want
 
 
-def resolve_perf_model(spec: str, cfg: ModelConfig, *, platform: str = "a10",
+def resolve_perf_model(spec: str, cfg: ModelConfig, *,
+                       platform: Optional[str] = None,
                        profile_cache: Optional[str] = None,
                        profile_grid: Optional[Dict[str, Tuple[int, ...]]]
                        = None, host_kv_dtype: str = "fp32"):

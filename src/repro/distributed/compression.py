@@ -11,8 +11,7 @@ Two families live here:
   * **Host-KV quantization + cold-page codec** — numpy-side symmetric
     int8 with one scale per token row (``quantize_kv_rows``), used by
     the paged host pool to store KV at 1 byte/element, and a lossless
-    byte codec (zstd when the ``zstandard`` wheel is importable, stdlib
-    zlib otherwise) that the pool uses to squeeze cold pages further.
+    zstd byte codec that the pool uses to squeeze cold pages further.
     Per-row scaling makes requantization of dequantized rows exact:
     the max-magnitude element of a row always maps back to ±127, so
     the recomputed scale equals the original and int8 codes round-trip
@@ -20,17 +19,12 @@ Two families live here:
 """
 from __future__ import annotations
 
-import zlib
 from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:                                    # optional; CI installs the wheel
-    import zstandard
-except ModuleNotFoundError:             # pragma: no cover - env dependent
-    zstandard = None
+import zstandard
 
 
 def quantize_int8(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -73,25 +67,18 @@ def dequantize_kv_rows(q: np.ndarray, scales: np.ndarray) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Lossless page codec (cold host-KV pages) — zstd with a zlib fallback
+# Lossless page codec (cold host-KV pages) — zstd
 # ---------------------------------------------------------------------------
-
-PAGE_CODEC = "zstd" if zstandard is not None else "zlib"
 
 
 def compress_page_bytes(raw: bytes) -> bytes:
-    """Losslessly compress one page blob (zstd if available, else zlib).
-    Both codecs are bit-exact on decompress, so compressed cold pages
-    never change tokens."""
-    if zstandard is not None:
-        return zstandard.ZstdCompressor(level=3).compress(raw)
-    return zlib.compress(raw, 6)
+    """Losslessly compress one page blob.  zstd is bit-exact on
+    decompress, so compressed cold pages never change tokens."""
+    return zstandard.ZstdCompressor(level=3).compress(raw)
 
 
 def decompress_page_bytes(blob: bytes) -> bytes:
-    if zstandard is not None:
-        return zstandard.ZstdDecompressor().decompress(blob)
-    return zlib.decompress(blob)
+    return zstandard.ZstdDecompressor().decompress(blob)
 
 
 def compress_decompress_with_feedback(grads: Any, error_feedback: Optional[Any]

@@ -44,8 +44,8 @@ def _decode_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
     length = lengths_ref[b]
     q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)         # (BS, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)         # (BS, D)
+    k = k_ref[0].astype(jnp.float32)               # (BS, D)
+    v = v_ref[0].astype(jnp.float32)               # (BS, D)
 
     scores = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
@@ -82,6 +82,8 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     q: (B, H, D) fresh-token queries; k, v: (B, S, KV, D) contiguous
     cache; lengths: (B,) valid token counts (the fresh token's K/V must
     already be written at index lengths-1).  Returns (B, H, D).
+    Compiled on TPU, D must be a multiple of 128 (or KV == 1) and
+    block_s a multiple of 8; interpret mode takes any shape.
     """
     b, h, d = q.shape
     _, s, kv, _ = k.shape
@@ -93,6 +95,11 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         s += pad
     qg = q.reshape(b, kv, g, d)
+    # K/V viewed as (B, S, KV*D): kv head ``hi`` is column block ``hi``
+    # of width D, so every block's trailing dims are (block_s, D) — the
+    # (8, 128) tiling the TPU lowering requires when D % 128 == 0
+    k = k.reshape(b, s, kv * d)
+    v = v.reshape(b, s, kv * d)
     scale = 1.0 / math.sqrt(d)
 
     grid = (b, kv, s // block_s)
@@ -103,10 +110,10 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, g, d), lambda bi, hi, si, _: (bi, hi, 0, 0)),
-                pl.BlockSpec((1, block_s, 1, d),
-                             lambda bi, hi, si, _: (bi, si, hi, 0)),
-                pl.BlockSpec((1, block_s, 1, d),
-                             lambda bi, hi, si, _: (bi, si, hi, 0)),
+                pl.BlockSpec((1, block_s, d),
+                             lambda bi, hi, si, _: (bi, si, hi)),
+                pl.BlockSpec((1, block_s, d),
+                             lambda bi, hi, si, _: (bi, si, hi)),
             ],
             out_specs=pl.BlockSpec((1, 1, g, d),
                                    lambda bi, hi, si, _: (bi, hi, 0, 0)),

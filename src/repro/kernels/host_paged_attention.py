@@ -61,7 +61,7 @@ def _attention_core(q, k, v, lengths, s):
     return out.reshape(b, h, d)
 
 
-@functools.partial(jax.jit, static_argnames=("page_size",), backend="cpu")
+@functools.partial(jax.jit, static_argnames=("page_size",))
 def _paged_attention_impl(q, pages, page_table, lengths, *, page_size: int):
     """q: (B, H, D); pages: (2, P, page_size, KV, D);
     page_table: (B, MP) int32; lengths: (B,).  Returns (B, H, D) f32."""
@@ -77,7 +77,7 @@ def _paged_attention_impl(q, pages, page_table, lengths, *, page_size: int):
     return _attention_core(q, k, v, lengths, s)
 
 
-@functools.partial(jax.jit, static_argnames=("page_size",), backend="cpu")
+@functools.partial(jax.jit, static_argnames=("page_size",))
 def _paged_attention_quant_impl(q, pages, scales, page_table, lengths, *,
                                 page_size: int):
     """Quantized variant: pages int8, scales (2, P, page_size) fp32 —
@@ -98,8 +98,9 @@ def _paged_attention_quant_impl(q, pages, scales, page_table, lengths, *,
 def host_paged_attention(q, pages, page_table, lengths, *, page_size: int,
                          scales=None):
     """Host (CPU-tier) paged attention.  Always executes on the CPU
-    backend regardless of the default device.  ``scales`` selects the
-    fused-dequant int8 path (see module docstring)."""
+    backend regardless of the default device: the inputs are placed on
+    the CPU device, and jit runs where its inputs live.  ``scales``
+    selects the fused-dequant int8 path (see module docstring)."""
     cpu = _cpu_device()
     if scales is None:
         args = jax.device_put((q, pages, page_table, lengths), cpu)

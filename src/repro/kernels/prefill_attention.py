@@ -56,9 +56,9 @@ def _prefill_kernel(prefix_ref, qoff_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, :, 0].astype(jnp.float32)       # (BQ, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)       # (BK, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)             # (BQ, D)
+        k = k_ref[0].astype(jnp.float32)             # (BK, D)
+        v = v_ref[0].astype(jnp.float32)
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale           # (BQ, BK)
@@ -88,8 +88,8 @@ def _prefill_kernel(prefix_ref, qoff_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ki == num_k - 1)
     def _finish():
         l = l_ref[:, :1]
-        o_ref[0, :, 0] = (acc_ref[...] / jnp.maximum(l, 1e-30)
-                          ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -106,7 +106,9 @@ def prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     optional prefix-LM boundary; q_offset: (B,) optional absolute
     position of each row's first query (chunked prefill — k/v then
     cover the accumulated cache span, causality is enforced on
-    absolute positions).  Returns (B, T, H, D).
+    absolute positions).  Returns (B, T, H, D).  Compiled on TPU, D
+    must be a multiple of 128 (or H == KV == 1); interpret mode takes
+    any shape.
     """
     b, t, h, d = q.shape
     s, kv = k.shape[1], k.shape[2]
@@ -115,9 +117,13 @@ def prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     block_k = min(block_k, s)
     tq = -(-t // block_q) * block_q
     tk = -(-s // block_k) * block_k
-    qp = jnp.pad(q, ((0, 0), (0, tq - t), (0, 0), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, tk - s), (0, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, tk - s), (0, 0), (0, 0)))
+    # heads folded into the lane axis — (B, T, H*D) and (B, S, KV*D) —
+    # so head ``hi`` is column block ``hi`` of width D and every block's
+    # trailing dims are (block, D): the (8, 128) tiling the TPU
+    # lowering requires when D % 128 == 0
+    qp = jnp.pad(q, ((0, 0), (0, tq - t), (0, 0), (0, 0))).reshape(b, tq, h * d)
+    kp = jnp.pad(k, ((0, 0), (0, tk - s), (0, 0), (0, 0))).reshape(b, tk, kv * d)
+    vp = jnp.pad(v, ((0, 0), (0, tk - s), (0, 0), (0, 0))).reshape(b, tk, kv * d)
     if prefix_len is None:
         prefix_len = jnp.zeros((b,), jnp.int32)
     if q_offset is None:
@@ -132,23 +138,23 @@ def prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, block_q, 1, d),
-                             lambda bi, hi, qi, ki, *_: (bi, qi, hi, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda bi, hi, qi, ki, *_, g_=g: (bi, ki, hi // g_, 0)),
-                pl.BlockSpec((1, block_k, 1, d),
-                             lambda bi, hi, qi, ki, *_, g_=g: (bi, ki, hi // g_, 0)),
+                pl.BlockSpec((1, block_q, d),
+                             lambda bi, hi, qi, ki, *_: (bi, qi, hi)),
+                pl.BlockSpec((1, block_k, d),
+                             lambda bi, hi, qi, ki, *_, g_=g: (bi, ki, hi // g_)),
+                pl.BlockSpec((1, block_k, d),
+                             lambda bi, hi, qi, ki, *_, g_=g: (bi, ki, hi // g_)),
             ],
-            out_specs=pl.BlockSpec((1, block_q, 1, d),
-                                   lambda bi, hi, qi, ki, *_: (bi, qi, hi, 0)),
+            out_specs=pl.BlockSpec((1, block_q, d),
+                                   lambda bi, hi, qi, ki, *_: (bi, qi, hi)),
             scratch_shapes=[
                 pltpu.VMEM((block_q, 128), jnp.float32),
                 pltpu.VMEM((block_q, 128), jnp.float32),
                 pltpu.VMEM((block_q, d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, tq, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, tq, h * d), q.dtype),
         interpret=interpret,
     )(prefix_len, q_offset, qp, kp, vp)
     # rows past t attended nothing (l=0, guarded divide) — slice away
-    return out[:, :t]
+    return out.reshape(b, tq, h, d)[:, :t]

@@ -1,7 +1,9 @@
 """HTTP/SSE gateway launcher: the production front door.
 
-Builds a reduced-geometry model once, fronts ``--replicas`` engine
-replicas with the asyncio gateway, and serves::
+Builds ``--arch`` once — at its published widths, or reduced with
+``--layers``/``--d-model`` — fronts ``--replicas`` engine replicas
+(replica i on ``jax.devices()[i % device_count]``, so a four-chip host
+runs one replica per chip) with the asyncio gateway, and serves::
 
     POST /v1/chat     SSE token stream ({"prompt": [ids], "deadline",
                       "priority", "max_new_tokens"})
@@ -9,7 +11,7 @@ replicas with the asyncio gateway, and serves::
     GET  /metrics     Prometheus text format
 
     PYTHONPATH=src python -m repro.launch.gateway --replicas 2 \
-        --port 8080 --max-queue-depth 64
+        --layers 4 --d-model 128 --port 8080 --max-queue-depth 64
 
 ``--smoke-test`` instead runs an in-process closed-loop client burst
 against the freshly started gateway, asserts non-empty SSE streams, a
@@ -17,9 +19,9 @@ green ``/health`` and parseable ``/metrics``, then exits non-zero on
 any failure (the CI gateway smoke step).
 
 The perf-model flags mirror ``repro.launch.serve``; the default here
-is ``analytic`` (instant startup — ``measured`` would profile at
-every replica build, including respawns; point ``--profile-cache`` at
-a shared file to make that cheap).
+is ``analytic`` at the attached device's platform (instant startup —
+``measured`` would profile at every replica build, including respawns;
+point ``--profile-cache`` at a shared file to make that cheap).
 """
 from __future__ import annotations
 
@@ -32,8 +34,9 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import get_config
-from repro.models import init_params
+from repro.launch.compile_cache import configure_compile_cache
+from repro.launch.serve import (add_model_args, host_pool_pages,
+                                init_weights, model_config)
 from repro.serving import InferenceServer, ServerConfig
 from repro.serving.gateway import EngineReplicaPool, serve_in_thread
 from repro.serving.gateway.client import get_json, get_text, sse_chat
@@ -49,9 +52,7 @@ def build_parser() -> argparse.ArgumentParser:
                     help="bounded gateway queue: submissions beyond this "
                          "in-flight depth shed with HTTP 503")
     # model / engine flags (mirroring repro.launch.serve)
-    ap.add_argument("--arch", default="llama3.1-8b")
-    ap.add_argument("--d-model", type=int, default=128)
-    ap.add_argument("--layers", type=int, default=4)
+    add_model_args(ap)
     ap.add_argument("--device-slots", type=int, default=4)
     ap.add_argument("--host-slots", type=int, default=8)
     ap.add_argument("--cache-len", type=int, default=128)
@@ -66,7 +67,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--cold-page-compress-after", type=float, default=0.0,
                     help="compress idle host KV pages after this many "
                          "seconds (0 = off)")
-    ap.add_argument("--platform", default="a10")
+    ap.add_argument("--platform", default=None,
+                    help="analytic perf-model platform (default: the "
+                         "attached device's, by device_kind)")
     ap.add_argument("--perf-model", default="analytic",
                     help="perf-model spec per replica: analytic | "
                          "analytic:<platform> | measured | file:<path>")
@@ -100,12 +103,13 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def build_pool(args: argparse.Namespace) -> EngineReplicaPool:
-    cfg = get_config(args.arch).reduced(layers=args.layers,
-                                        d_model=args.d_model, vocab=512)
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    cfg = model_config(args)
+    params = init_weights(cfg, args.seed)
     scfg = ServerConfig(
         device_slots=args.device_slots, host_slots=args.host_slots,
         cache_len=args.cache_len, enable_offload=not args.no_offload,
+        host_pool_pages=host_pool_pages(cfg, host_slots=args.host_slots,
+                                        cache_len=args.cache_len),
         host_workers=args.host_workers, chunk_tokens=args.chunk_tokens,
         host_kv_dtype=args.host_kv_dtype,
         cold_page_compress_after=args.cold_page_compress_after,
@@ -121,13 +125,17 @@ def build_pool(args: argparse.Namespace) -> EngineReplicaPool:
           f"{args.replicas} replicas x (device_slots={scfg.device_slots} "
           f"host_slots={scfg.host_slots}) perf_model={scfg.perf_model}")
 
-    def factory() -> InferenceServer:
+    def factory(device) -> InferenceServer:
         # each replica gets its own config copy (engines mutate knobs
         # like enable_offload for inapplicable stacks); params are
-        # read-only and shared across replicas
-        return InferenceServer(cfg, params, dataclasses.replace(scfg))
+        # read-only, one copy per device
+        return InferenceServer(cfg, jax.device_put(params, device),
+                               dataclasses.replace(scfg))
 
-    return EngineReplicaPool(factory, replicas=args.replicas)
+    devices = jax.devices()
+    return EngineReplicaPool(
+        factory, replicas=args.replicas,
+        devices=[devices[i % len(devices)] for i in range(args.replicas)])
 
 
 def smoke_test(pool: EngineReplicaPool, args: argparse.Namespace) -> int:
@@ -190,6 +198,7 @@ def smoke_test(pool: EngineReplicaPool, args: argparse.Namespace) -> int:
 
 
 def main() -> None:
+    configure_compile_cache()
     args = build_parser().parse_args()
     pool = build_pool(args)
     try:

@@ -1,17 +1,25 @@
 """End-to-end online-serving driver (deliverable (b), the paper's kind).
 
-Serves a reduced-geometry model through the scheduler-driven
-``InferenceServer``: requests come from a paper workload trace
-(``--workload``) or the synthetic default, and Algorithm 1 picks the
-execution strategy every iteration.  In closed-loop mode the first
-response streams token by token; with ``--arrival-rate`` the trace is
-instead replayed open-loop in wall-clock time (no streaming demo).
-APEX offload is exact: host rows emit the same tokens a
-device-resident run would (tests/test_overlap.py enforces this).
+Serves ``--arch`` at its published widths (random weights from
+``--seed``) through the scheduler-driven ``InferenceServer``;
+``--layers``/``--d-model`` instead serve a reduced-geometry model of
+the same family (the CPU smoke size).  Requests come from a paper
+workload trace (``--workload``) or the synthetic default, and
+Algorithm 1 picks the execution strategy every iteration.  In
+closed-loop mode the first response streams token by token; with
+``--arrival-rate`` the trace is instead replayed open-loop in
+wall-clock time (no streaming demo).  APEX offload is exact on CPU:
+host rows emit the same tokens a device-resident run would
+(tests/test_overlap.py enforces this).
 
+    # published widths (one TPU v5e chip)
+    PYTHONPATH=src python -m repro.launch.serve --arch internlm2-1.8b \
+        --requests 24 --device-slots 8 --host-slots 8 --cache-len 2048
+
+    # reduced widths (CPU)
     PYTHONPATH=src python -m repro.launch.serve --arch llama3.1-8b \
-        --requests 16 --device-slots 2 --host-slots 6 \
-        --workload azure-conv
+        --layers 4 --d-model 128 --requests 16 --device-slots 2 \
+        --host-slots 6 --workload azure-conv
 """
 from __future__ import annotations
 
@@ -23,24 +31,66 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import init_params
+from repro.models.config import ModelConfig
 from repro.serving import InferenceServer, ServerConfig
 from repro.serving.workloads import WORKLOADS
 
 
+def add_model_args(ap: argparse.ArgumentParser) -> None:
+    """Model flags shared by the serving entry points."""
+    ap.add_argument("--arch", default="internlm2-1.8b")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="serve a reduced-geometry model of --arch with "
+                         "this many layers (default: published widths)")
+    ap.add_argument("--d-model", type=int, default=None,
+                    help="serve a reduced-geometry model of --arch at "
+                         "this width (default: published widths)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and of the "
+                         "generated requests")
+
+
+def model_config(args: argparse.Namespace) -> ModelConfig:
+    """``--arch`` as published, or reduced (4 heads, vocab 512) when
+    ``--layers`` or ``--d-model`` is given."""
+    cfg = get_config(args.arch)
+    if args.layers is None and args.d_model is None:
+        return cfg
+    return cfg.reduced(layers=args.layers or 4, d_model=args.d_model or 128,
+                       vocab=512)
+
+
+def init_weights(cfg: ModelConfig, seed: int):
+    """Random params from ``seed``, built by one compiled program (an
+    eager init at published widths dispatches op by op and takes
+    minutes on a TPU)."""
+    return jax.jit(init_params, static_argnums=1)(
+        jax.random.PRNGKey(seed), cfg)
+
+
+def host_pool_pages(cfg: ModelConfig, *, host_slots: int,
+                    cache_len: int) -> int:
+    """Paged host-pool size that holds every host slot at full
+    ``cache_len`` (pages are per attention layer)."""
+    return (host_slots * -(-cache_len // ServerConfig.page_size)
+            * max(cfg.num_attn_layers, 1))
+
+
 def main() -> None:
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama3.1-8b")
-    ap.add_argument("--d-model", type=int, default=128)
-    ap.add_argument("--layers", type=int, default=4)
+    add_model_args(ap)
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--output-len", type=int, default=24)
     ap.add_argument("--device-slots", type=int, default=4)
     ap.add_argument("--host-slots", type=int, default=8)
     ap.add_argument("--cache-len", type=int, default=128)
-    ap.add_argument("--platform", default="a10",
-                    help="platform backing the analytic perf-model specs")
+    ap.add_argument("--platform", default=None,
+                    help="platform backing the analytic perf-model specs "
+                         "(default: the attached device's, by device_kind)")
     ap.add_argument("--perf-model", default="measured",
                     help="perf-model spec feeding Algorithm 1: analytic | "
                          "analytic:<platform> | measured | file:<path> "
@@ -115,11 +165,12 @@ def main() -> None:
                     help="suppress the per-token stream of request 0")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced(layers=args.layers,
-                                        d_model=args.d_model, vocab=512)
+    cfg = model_config(args)
     scfg = ServerConfig(
         device_slots=args.device_slots, host_slots=args.host_slots,
         cache_len=args.cache_len, enable_offload=not args.no_offload,
+        host_pool_pages=host_pool_pages(cfg, host_slots=args.host_slots,
+                                        cache_len=args.cache_len),
         host_workers=args.host_workers,
         bucketed_prefill=not args.no_bucketed_prefill,
         host_kv_dtype=args.host_kv_dtype,
@@ -137,13 +188,14 @@ def main() -> None:
         workload=None if args.workload in (None, "synthetic")
         else args.workload,
         num_requests=args.requests, arrival_rate=args.arrival_rate,
-        prompt_len=args.prompt_len, output_len=args.output_len)
+        prompt_len=args.prompt_len, output_len=args.output_len,
+        seed=args.seed)
     print(f"serving {cfg.name}: {cfg.param_count()/1e6:.1f}M params; "
           f"device_slots={scfg.device_slots} host_slots={scfg.host_slots} "
           f"offload={scfg.enable_offload} "
           f"workload={scfg.workload or 'synthetic'} "
           f"perf_model={scfg.perf_model}")
-    params = init_params(jax.random.PRNGKey(0), cfg)
+    params = init_weights(cfg, args.seed)
     if scfg.perf_model == "measured" and not (
             scfg.profile_cache and os.path.exists(scfg.profile_cache)):
         print("profiling backends at startup (use --profile-cache to "

@@ -122,7 +122,7 @@ class PagedKVPool:
 
     **Cold pages.** With ``cold_page_compress_after > 0`` pages whose
     owner has been idle past that many seconds are losslessly
-    compressed (zstd, or zlib when unavailable): the raw page bytes
+    compressed (zstd): the raw page bytes
     (and scale rows) move into a side blob dict keyed by a negative
     sentinel id spliced into the page chains, and the physical page
     returns to the free list — that is the capacity win, since the pool

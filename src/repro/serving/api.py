@@ -93,7 +93,9 @@ class ServerConfig:
     perf_model: str = "analytic"
     profile_cache: Optional[str] = None
     profile_grid: Optional[dict] = None     # override startup profile points
-    platform: str = "a10"            # analytic perf-model calibration
+    # analytic perf-model calibration; None = the attached device's
+    # (repro.core.perf_model.device_platform)
+    platform: Optional[str] = None
     host_min_ratio: float = 0.0      # §4.2 admission threshold
     max_pipeline_sub_batch: int = 256
     use_scheduler: bool = True

@@ -190,6 +190,7 @@ class Engine:
             # can be abandoned and recomputed exactly on this thread
             self._pending_meta: Optional[dict] = None
             self._pending_deadline = 0.0
+            self._pending_timeout = 0.0
             # circuit breaker over consecutive watchdog fallbacks: while
             # open (now < _breaker_until) no host jobs are submitted and
             # no new host placements/demotions happen — GPU_ONLY pin;
@@ -490,7 +491,7 @@ class Engine:
         return (self._executor is not None
                 and time.perf_counter() < self._breaker_until)
 
-    def _host_fallback(self) -> np.ndarray:
+    def _host_fallback(self, cause: str) -> np.ndarray:
         """Watchdog recovery: abandon the pending host job (stalled
         past its deadline or died with an exception) and rerun it
         synchronously on the engine thread through the executor's
@@ -507,6 +508,8 @@ class Engine:
             meta["q"], meta["k"], meta["v"], meta["positions"],
             rows=meta["rows"])
         self.stats.host_fallbacks += 1
+        causes = self.stats.host_fallback_causes
+        causes[cause] = causes.get(cause, 0) + 1
         self._fallback_streak += 1
         # the recovery's wall time says nothing about a healthy host
         # tier — never feed it to the calibrator
@@ -871,7 +874,7 @@ class Engine:
                         raise TimeoutError(
                             f"host job {self._pending_job} missed its "
                             "watchdog deadline")
-            except (RuntimeError, TimeoutError):
+            except (RuntimeError, TimeoutError) as exc:
                 # worker exception (RuntimeError via _unwrap) or
                 # watchdog expiry: abandon the job and recompute its
                 # attention exactly on this thread.  Without the
@@ -879,7 +882,10 @@ class Engine:
                 # fail the engine loudly.
                 if not self.e.recompute_fallback:
                     raise
-                out = self._host_fallback()
+                err = exc.__cause__
+                out = self._host_fallback(
+                    "timeout" if err is None
+                    else f"{type(err).__name__}: {err}"[:160])
                 fell_back = True
             if out is None:
                 host_idle = ctl.host_io(cohort)._replace(
@@ -976,9 +982,16 @@ class Engine:
                 pred = self._calibrator.t_catt(len(valid), mean_pos,
                                                layers=1)
                 self._pending_host_pred = pred
-            self._pending_deadline = time.perf_counter() + max(
-                pred * self.e.host_job_slack, self.e.host_job_min_timeout)
+            # the watchdog clock starts below, once this step's outputs
+            # (the job's inputs) exist: the device step's own time is
+            # never charged to the host job
+            self._pending_timeout = max(pred * self.e.host_job_slack,
+                                        self.e.host_job_min_timeout)
+            self._pending_deadline = 0.0
         self._commit_device(logits, active_rows)
+        if self._pending_job is not None and not self._pending_deadline:
+            self._pending_deadline = time.perf_counter() \
+                + self._pending_timeout
         cohort.x_carry = x_final[self.e.device_slots:]
         if completes:
             row_idx = [self.e.device_slots + i for i in valid]

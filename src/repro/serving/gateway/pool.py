@@ -24,6 +24,12 @@ Contracts:
     turns land where the engine's prefix cache already holds the
     conversation's KV.  A dead or respawned pin (generation mismatch)
     falls back to least-loaded routing and re-pins there.
+  * **One replica per device.** Given ``devices``, replica i is built
+    by ``factory(devices[i])`` under ``jax.default_device(devices[i])``
+    and its driver loop runs under the same context, so its params
+    (which the factory places with ``jax.device_put``), decode state
+    and every step live on that device: one process drives all chips
+    of a host.
   * **Crash containment + respawn.** A driver exception marks the
     replica dead, fails every in-flight request on it (the error lands
     on ``Request.error`` / the stream's terminal event — other
@@ -40,6 +46,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, \
     Tuple, Union
+
+import jax
 
 from repro.distributed.fault_tolerance import HeartbeatMonitor
 from repro.serving.api import InferenceServer
@@ -213,11 +221,14 @@ class Replica:
     #                          condition-notify braces on submit)
 
     def __init__(self, index: int,
-                 factory: Callable[[], InferenceServer], *,
-                 generation: int = 0) -> None:
+                 factory: Callable[..., InferenceServer], *,
+                 generation: int = 0, device: Any = None) -> None:
         self.index = index
         self.generation = generation     # bumped on every respawn
-        self.server = factory()
+        self.device = device             # None: JAX's default device
+        with self._on_device():
+            self.server = (factory() if device is None
+                           else factory(device))
         self.alive = True
         self.error: Optional[str] = None
         self.leases = 0
@@ -230,6 +241,10 @@ class Replica:
         self._on_death: Optional[Callable[["Replica"], None]] = None
         self._thread = threading.Thread(
             target=self._drive, name=f"replica-{index}-driver", daemon=True)
+
+    def _on_device(self):
+        return (contextlib.nullcontext() if self.device is None
+                else jax.default_device(self.device))
 
     def start(self, on_death: Optional[Callable[["Replica"], None]] = None
               ) -> None:
@@ -304,6 +319,10 @@ class Replica:
             self.on_beat(self.index)
 
     def _drive(self) -> None:
+        with self._on_device():
+            self._drive_loop()
+
+    def _drive_loop(self) -> None:
         try:
             while True:
                 with self._cond:
@@ -401,17 +420,24 @@ class EngineReplicaPool:
     predicted-wait estimate the gateway's admission backpressure uses.
 
     ``factory`` builds one configured ``InferenceServer`` (replicas
-    typically share the model params — they are read-only)."""
+    typically share the model params — they are read-only).  With
+    ``devices`` (one per replica) it is called as ``factory(device)``
+    and must place that replica's params on ``device``."""
 
     # sticky-session table bound: oldest pins fall off first (a pin is
     # only a routing hint — losing one degrades to least-loaded)
     _SESSION_CAP = 4096
 
-    def __init__(self, factory: Callable[[], InferenceServer], *,
+    def __init__(self, factory: Callable[..., InferenceServer], *,
                  replicas: int = 2, auto_respawn: bool = True,
-                 heartbeat_timeout: float = 60.0) -> None:
+                 heartbeat_timeout: float = 60.0,
+                 devices: Optional[Sequence[Any]] = None) -> None:
         if replicas < 1:
             raise ValueError("need at least one replica")
+        if devices is not None and len(devices) != replicas:
+            raise ValueError(f"{len(devices)} devices for {replicas} "
+                             f"replicas; give one device per replica")
+        self._devices = list(devices) if devices is not None else None
         self._factory = factory
         self._auto_respawn = auto_respawn
         self._lock = threading.Lock()
@@ -426,10 +452,14 @@ class EngineReplicaPool:
         # containment path already marks dead and respawns)
         self._heartbeats = HeartbeatMonitor(
             range(replicas), timeout=heartbeat_timeout)
-        self.replicas: List[Replica] = [Replica(i, factory)
-                                        for i in range(replicas)]
+        self.replicas: List[Replica] = [
+            Replica(i, factory, device=self._device(i))
+            for i in range(replicas)]
         for rep in self.replicas:
             self._start_replica(rep)
+
+    def _device(self, index: int) -> Any:
+        return None if self._devices is None else self._devices[index]
 
     def _start_replica(self, rep: Replica) -> None:
         rep.on_beat = self._beat
@@ -449,7 +479,8 @@ class EngineReplicaPool:
                 return
         try:
             fresh = Replica(dead.index, self._factory,
-                            generation=dead.generation + 1)
+                            generation=dead.generation + 1,
+                            device=self._device(dead.index))
         except Exception:
             return        # factory broken too: /health keeps it dead
         with self._lock:

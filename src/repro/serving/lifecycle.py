@@ -102,14 +102,15 @@ class EngineConfig:
     # Algorithm-1 scheduling: the perf-model spec resolved by
     # PerfModelProvider ("analytic" | "analytic:<platform>" |
     # "measured" | "file:<path>"), the platform backing the analytic
-    # specs, and the §4.2 knobs passed to ApexScheduler.  "measured"
+    # specs (None = the attached device's, by device_kind), and the
+    # §4.2 knobs passed to ApexScheduler.  "measured"
     # runs the OfflineProfiler once at engine startup (loading/saving
     # profile_cache when set); the resolved model is wrapped in an
     # OnlineCalibrator that refines it from observed iteration timings.
     perf_model: str = "analytic"
     profile_cache: Optional[str] = None
     profile_grid: Optional[Dict[str, tuple]] = None
-    platform: str = "a10"
+    platform: Optional[str] = None
     host_min_ratio: float = 0.0
     max_pipeline_sub_batch: int = 256
     use_scheduler: bool = True
@@ -257,6 +258,10 @@ class EngineStats:
     # threshold reached -> GPU_ONLY pin for a cooldown window)
     host_fallbacks: int = 0
     host_breaker_trips: int = 0
+    # what each watchdog fallback recovered from: "timeout", or the
+    # worker's exception as "<type>: <message>"
+    host_fallback_causes: Dict[str, int] = dataclasses.field(
+        default_factory=dict)
     # requests aborted by the client (gateway disconnects,
     # PoolHandle.cancel, Engine.cancel) with their resources freed
     cancelled: int = 0

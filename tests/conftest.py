@@ -1,42 +1,8 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests run on the single
-real CPU device; only launch/dryrun.py fakes 512 devices.
-
-When the container lacks ``hypothesis``, a stub is installed whose
-``@given`` replaces the test with a runtime ``pytest.skip`` — property
-tests skip cleanly instead of erroring the whole module at collection,
-and every example-based test in those modules still runs."""
-import sys
-import types
-
+real CPU device; only launch/dryrun.py fakes 512 devices."""
 import jax
 import numpy as np
 import pytest
-
-try:
-    import hypothesis                                    # noqa: F401
-except ModuleNotFoundError:
-    def _given(*_a, **_k):
-        def deco(fn):
-            def wrapper():
-                pytest.skip("hypothesis not installed")
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            return wrapper
-        return deco
-
-    def _settings(*_a, **_k):
-        return lambda fn: fn
-
-    class _Strategies(types.ModuleType):
-        def __getattr__(self, name):
-            return lambda *a, **k: None
-
-    _mod = types.ModuleType("hypothesis")
-    _mod.given = _given
-    _mod.settings = _settings
-    _mod.strategies = _Strategies("hypothesis.strategies")
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _mod.strategies
 
 
 @pytest.fixture(scope="session")

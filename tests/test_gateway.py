@@ -77,6 +77,39 @@ def test_concurrent_submission_bit_identical_to_serial(served):
     assert results == serial           # bit-identical, all 8 present
 
 
+def test_pool_places_each_replica_on_its_device(served):
+    """With ``devices`` the pool calls ``factory(device)`` under that
+    device's default-device context, and each replica's driver steps
+    there: every array of a replica's engine lives on its device, and
+    the outputs equal a plain single-server run."""
+    cfg, params = served
+    dev = jax.devices()[0]
+    seen = []
+
+    def factory(device):
+        seen.append((device, jax.config.jax_default_device))
+        return InferenceServer(cfg, jax.device_put(params, device),
+                               ServerConfig(device_slots=2, host_slots=3,
+                                            cache_len=64, output_len=5))
+
+    with pytest.raises(ValueError, match="one device per replica"):
+        EngineReplicaPool(factory, replicas=2, devices=[dev])
+    prompts = _prompts(4)
+    with InferenceServer(cfg, params,
+                         ServerConfig(device_slots=2, host_slots=3,
+                                      cache_len=64, output_len=5)) as ref:
+        serial = [ref.submit(p, max_new_tokens=5).result() for p in prompts]
+    with EngineReplicaPool(factory, replicas=2, devices=[dev, dev]) as pool:
+        outs = [pool.submit(p, 5).result(timeout=120.0) for p in prompts]
+        for rep in pool.replicas:
+            assert rep.device == dev
+            leaves = jax.tree.leaves((rep.server.engine.params,
+                                      rep.server.engine.state))
+            assert {d for leaf in leaves for d in leaf.devices()} == {dev}
+    assert seen == [(dev, dev), (dev, dev)]
+    assert outs == serial
+
+
 def test_step_lock_allows_concurrent_token_iterators(served):
     """Satellite 1: two RequestHandle.tokens() iterators pulled from
     different threads both drive step(); the lock serializes them."""

@@ -196,3 +196,28 @@ def test_mamba_scan_carries_state_across_calls(key):
                                  block_i=64, interpret=True)
     np.testing.assert_allclose(np.concatenate([y1, y2], 1),
                                np.asarray(y_full), atol=2e-5, rtol=2e-5)
+
+
+def test_ops_on_tpu_never_interpret_nor_reference(monkeypatch):
+    """On a TPU the public wrappers always run the compiled Pallas
+    kernel: neither interpret mode nor the jnp reference, whatever
+    REPRO_INTERPRET_KERNELS says."""
+    from repro.kernels import ops
+    calls = {}
+
+    def record(name):
+        return lambda *a, **kw: calls.setdefault(name, kw)
+
+    def refuse(*a, **kw):
+        raise AssertionError("reference path taken on a TPU")
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setenv("REPRO_INTERPRET_KERNELS", "0")
+    monkeypatch.setattr(ops._dec, "decode_attention", record("decode"))
+    monkeypatch.setattr(ops._pre, "prefill_attention", record("prefill"))
+    monkeypatch.setattr(ops._ref, "decode_attention_ref", refuse)
+    monkeypatch.setattr(ops._ref, "prefill_attention_ref", refuse)
+    ops.decode_attention(None, None, None, None)
+    ops.prefill_attention(None, None, None)
+    assert calls["decode"]["interpret"] is False
+    assert calls["prefill"]["interpret"] is False

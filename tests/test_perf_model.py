@@ -3,15 +3,17 @@ spec resolution, OfflineProfiler smoke (the CI tier-1 profiler check),
 TablePerfModel persistence/rates, OnlineCalibrator, and the measured
 model driving the live engine's scheduler."""
 import json
+import types
 
 import jax
 import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core.perf_model import (AnalyticPerfModel, OnlineCalibrator,
-                                   PerfModelProvider, TablePerfModel,
-                                   analytic_model, resolve_perf_model)
+from repro.core.perf_model import (DEVICE_PLATFORMS, AnalyticPerfModel,
+                                   OnlineCalibrator, PerfModelProvider,
+                                   TablePerfModel, analytic_model,
+                                   device_platform, resolve_perf_model)
 from repro.core.profiler import OfflineProfiler
 from repro.core.scheduler import ApexScheduler, StrategyKind
 from repro.models import init_params
@@ -49,6 +51,35 @@ def test_analytic_spec_resolution(tiny):
         resolve_perf_model("nonsense", cfg)
     with pytest.raises(ValueError):
         resolve_perf_model("file:/does/not/exist.json", cfg)
+
+
+@pytest.mark.parametrize("kind,expect", [
+    ("TPU v5 lite", "v5e"), ("TPU v5", None), ("TPU v6 lite", None),
+    ("TPU v4", None)])
+def test_default_platform_follows_device_kind(tiny, monkeypatch, kind,
+                                              expect):
+    """With no platform named, the analytic model prices Algorithm 1
+    with the attached device's rates (looked up by device_kind); a TPU
+    kind without an entry is an error, never the A10 default."""
+    cfg, _ = tiny
+    fake = types.SimpleNamespace(platform="tpu", device_kind=kind)
+    monkeypatch.setattr(jax, "devices", lambda *a: [fake])
+    if expect is None:
+        with pytest.raises(ValueError, match=kind):
+            resolve_perf_model("analytic", cfg)
+    else:
+        assert resolve_perf_model("analytic", cfg).platform.name == expect
+    # a named platform is used as named, whatever is attached
+    assert resolve_perf_model("analytic", cfg,
+                              platform="a10").platform.name == "a10"
+    assert resolve_perf_model("analytic:t4", cfg).platform.name == "t4"
+
+
+def test_cpu_backend_carries_the_paper_a10_calibration(tiny):
+    cfg, _ = tiny
+    assert device_platform() == DEVICE_PLATFORMS["cpu"] == "a10"
+    assert resolve_perf_model("analytic", cfg).platform.name == "a10"
+    assert ServerConfig().platform is None
 
 
 def test_file_spec_reuses_profile_without_reprofiling(tiny, measured,
