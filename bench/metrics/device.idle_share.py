@@ -1,0 +1,7 @@
+"""1 - (union of device-operation intervals / traced window)."""
+
+
+def read(w):
+    if w.trace is None or w.trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - w.trace["busy_s"] / w.trace["window_s"])
