@@ -1,7 +1,6 @@
 """Benchmark harness: one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV (the harness contract) and a
-roofline table from the dry-run artifacts when present.
+Prints ``name,us_per_call,derived`` CSV (the harness contract).
 
     PYTHONPATH=src python -m benchmarks.run [--only fig5]
 """
@@ -40,16 +39,6 @@ def main() -> None:
         except Exception as e:  # keep the harness running
             print(f"{name},NaN,ERROR:{type(e).__name__}:{e}", file=sys.stderr)
         print(f"# {name} done in {time.time() - t0:.1f}s", file=sys.stderr)
-
-    # roofline table (reads dry-run artifacts if they exist)
-    try:
-        from benchmarks import roofline
-        rows = roofline.table()
-        if rows and (not args.only or "roofline" in args.only):
-            print("\n# === Roofline (single-pod 16x16, from dry-run) ===")
-            print(roofline.render(rows))
-    except Exception as e:
-        print(f"# roofline unavailable: {e}", file=sys.stderr)
 
 
 if __name__ == "__main__":

@@ -33,9 +33,11 @@ import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.spans import span
 from repro.kernels.ops import host_paged_attention_numpy
 from repro.models.config import BlockKind, ModelConfig
 from repro.models.kv_cache import PagedKVPool
@@ -231,8 +233,9 @@ class HostExecutor:
     which case the engine's re-check semantics (paper §3.4 end) apply.
 
     Host-busy accounting is split so the calibrator's ``t_catt`` stays
-    honest: ``transfer_time`` (device→host materialization) vs
-    ``compute_time`` (KV append + paged attention); ``busy_time`` is
+    honest: ``transfer_time`` (device→host materialization, of which
+    ``device_wait_time`` waits for the device step that makes the QKV)
+    vs ``compute_time`` (KV append + paged attention); ``busy_time`` is
     their sum.  Callers may hand consumed result buffers back through
     ``recycle`` — unreturned buffers are simply allocated per job.
     """
@@ -261,6 +264,7 @@ class HostExecutor:
         self._queue: "queue.Queue[Optional[_Job]]" = queue.Queue()
         self._free_bufs: Dict[tuple, List[np.ndarray]] = {}
         self._transfer_time = 0.0
+        self._device_wait_time = 0.0
         self._compute_time = 0.0
         self._worker: Optional[threading.Thread] = None
         if not synchronous:
@@ -393,8 +397,15 @@ class HostExecutor:
 
     @property
     def transfer_time(self) -> float:
-        """Seconds spent materializing device QKV on the host."""
+        """Seconds spent materializing device QKV on the host: waiting
+        for the device step that makes it, then copying it."""
         return self._transfer_time
+
+    @property
+    def device_wait_time(self) -> float:
+        """The part of ``transfer_time`` spent waiting for the device
+        step that makes a job's QKV."""
+        return self._device_wait_time
 
     @property
     def compute_time(self) -> float:
@@ -430,14 +441,41 @@ class HostExecutor:
         import time
         if job.inject and self.faults is not None:
             self.faults.on_host_job()
-        t0 = time.perf_counter()
-        # device→host transfer (no-op for float32 numpy inputs): doing
-        # it here — not at submit — is the non-blocking handoff; the
-        # engine is already dispatching the next device step
-        q, k, v = _as_f32(job.q), _as_f32(job.k), _as_f32(job.v)
-        if job.rows is not None:
-            q, k, v = q[job.rows], k[job.rows], v[job.rows]
-        t1 = time.perf_counter()
+        with span("host_job", job=job.job_id, layer=job.layer):
+            t0 = time.perf_counter()
+            # device→host transfer (no-op for float32 numpy inputs): doing
+            # it here — not at submit — is the non-blocking handoff; the
+            # engine is already dispatching the next device step.  Device
+            # QKV first has to exist: the wait for the step that makes it
+            # is kept apart from the copy itself.
+            with span("host_job.device_wait"):
+                jax.block_until_ready([a for a in (job.q, job.k, job.v)
+                                       if isinstance(a, jax.Array)])
+            t_ready = time.perf_counter()
+            with span("host_job.copy"):
+                q, k, v = _as_f32(job.q), _as_f32(job.k), _as_f32(job.v)
+                if job.rows is not None:
+                    q, k, v = q[job.rows], k[job.rows], v[job.rows]
+            t1 = time.perf_counter()
+            with span("host_job.attention"):
+                out = self._attend(job, q, k, v)
+            t2 = time.perf_counter()
+        with self._done:
+            if job.job_id in self._abandoned:
+                # watchdog gave up on this job; its (identical) output
+                # was recomputed already — drop the late publish
+                self._abandoned.discard(job.job_id)
+                self._free_bufs.setdefault(out.shape, []).append(out)
+            else:
+                self._results[job.job_id] = out
+            self._device_wait_time += t_ready - t0
+            self._transfer_time += t1 - t0
+            self._compute_time += t2 - t1
+            self._done.notify_all()
+
+    def _attend(self, job: _Job, q, k, v) -> np.ndarray:
+        """Append the job's fresh K/V to the pool and run paged attention
+        for its rows into one output buffer."""
         li = self._pool_layer(job.layer)
         n = len(job.request_ids)
         # append the fresh token's K/V for this layer — one vectorized
@@ -478,18 +516,7 @@ class HostExecutor:
                 for a, b in zip(bounds[:-1], bounds[1:]) if b > a]
             for f in futs:
                 f.result()
-        t2 = time.perf_counter()
-        with self._done:
-            if job.job_id in self._abandoned:
-                # watchdog gave up on this job; its (identical) output
-                # was recomputed already — drop the late publish
-                self._abandoned.discard(job.job_id)
-                self._free_bufs.setdefault(out.shape, []).append(out)
-            else:
-                self._results[job.job_id] = out
-            self._transfer_time += t1 - t0
-            self._compute_time += t2 - t1
-            self._done.notify_all()
+        return out
 
     def advance_token(self, request_ids: Sequence[int]) -> None:
         """Bump pool lengths after a cohort completes a token."""

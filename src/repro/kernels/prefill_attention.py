@@ -6,8 +6,7 @@ innermost and accumulated sequentially in VMEM scratch.  Causality is
 enforced with an index mask; tiles entirely in the future contribute
 nothing (their scores are -inf) and are additionally skipped for
 compute via ``pl.when`` (the DMA still runs — on TPU the schedule is
-static; the roofline model in benchmarks/roofline counts causal FLOPs
-at 0.5x accordingly).
+static, so a roofline counts causal FLOPs at 0.5x).
 
 Supports prefix-LM masking (PaliGemma) via ``prefix_len``, and
 *chunked prefill* via ``q_offset``: queries are a T-token chunk whose

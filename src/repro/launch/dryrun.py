@@ -7,8 +7,7 @@ For every (architecture x shape) cell and both production meshes
 (16x16 single-pod, 2x16x16 multi-pod) this lowers + compiles the real
 step function against ShapeDtypeStruct inputs (no allocation), then
 records memory_analysis, cost_analysis, and the while-scaled
-collective-bytes breakdown (launch/analysis.py) into a JSON artifact
-that benchmarks/roofline.py reads.
+collective-bytes breakdown (launch/analysis.py) into a JSON artifact.
 
 Usage::
 

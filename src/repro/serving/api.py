@@ -387,6 +387,8 @@ class InferenceServer:
                 self.engine._executor.busy_time
             self.engine.stats.host_transfer_time = \
                 self.engine._executor.transfer_time
+            self.engine.stats.host_device_wait_time = \
+                self.engine._executor.device_wait_time
         self.engine._refresh_host_pool_gauges()
         return self.engine.stats
 

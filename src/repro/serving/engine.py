@@ -35,6 +35,7 @@ decode compile per (device_slots, host_slots) pair.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Dict, List, Optional
 
@@ -49,6 +50,7 @@ from repro.core.overlap_engine import (Cohort, HostExecutor,
 from repro.core.perf_model import OnlineCalibrator, resolve_perf_model
 from repro.core.scheduler import (AdmissionController, ApexScheduler,
                                   Decision, StrategyKind)
+from repro.core.spans import span, spanned, step_span
 from repro.distributed.fault_tolerance import RestartPolicy
 from repro.serving.faults import FaultInjector
 from repro.models import (HostIO, ModelParams, decode_step,
@@ -131,8 +133,7 @@ class Engine:
             admission=self.admission, perf_model=self._calibrator,
             iters_per_host_token=cfg.num_attn_layers + 1)
         self.lc = RequestLifecycle(self.e, stats=self.stats, placer=placer)
-        self._decode_fn = jax.jit(
-            lambda p, tok, st: decode_step(p, cfg, tok, st))
+        self._decode_fn = jax.jit(self._decode_traced)
         # hybrid (recurrent-state) stacks ride the same fast paths as
         # attention-only stacks: the length-masked scan (models.ssm)
         # freezes state past each row's true length, so bucketed and
@@ -202,8 +203,7 @@ class Engine:
                 max_restarts=1 << 30,
                 backoff_base=max(self.e.host_breaker_cooldown, 1e-3),
                 backoff_cap=max(self.e.host_breaker_cooldown, 1e-3) * 32)
-            self._decode_overlap_fn = jax.jit(
-                lambda p, tok, st, host: decode_step(p, cfg, tok, st, host))
+            self._decode_overlap_fn = jax.jit(self._decode_overlap_traced)
         # cross-request prefix cache: retired requests publish their KV
         # (device cache rows, overflowing to the paged host pool) and
         # admissions matching a cached prefix resume chunked prefill at
@@ -280,6 +280,14 @@ class Engine:
         return prefill_bucketed(params, self.cfg, tokens, plens,
                                 cache_len=self.e.cache_len)
 
+    # --- decode -------------------------------------------------------------
+    def _decode_traced(self, params: ModelParams, tokens, state):
+        return decode_step(params, self.cfg, tokens, state)
+
+    def _decode_overlap_traced(self, params: ModelParams, tokens, state,
+                               host):
+        return decode_step(params, self.cfg, tokens, state, host)
+
     # --- chunked prefill (fused with decode) ------------------------------
     def _chunk_traced(self, params: ModelParams, ctoks, clens, cstate):
         self._prefill_compiles += 1
@@ -352,6 +360,7 @@ class Engine:
         return [p[0] for p in placements]
 
     # --- tier moves (the placer decides; the engine moves the KV) ----------
+    @spanned("tier_move")
     def _migrate_host_to_device(self, req: Request, slot: int) -> None:
         """Promote a host resident into a freed device slot: gather its
         paged KV through the executor, upload into the slot's
@@ -368,6 +377,7 @@ class Engine:
         self._executor.free(req.request_id)
         self.lc.note_migrated(req, slot)
 
+    @spanned("tier_move")
     def _retarget_staging(self, req: Request, slot: int) -> None:
         """Mid-prefill host→device retarget: the staging row's KV
         already lives on device, so the move is pure bookkeeping —
@@ -415,6 +425,7 @@ class Engine:
             else:
                 self._migrate_host_to_device(cand, slot)
 
+    @spanned("tier_move")
     def _preempt_to_host(self, urgent: Request) -> Optional[int]:
         """Demote the placer-chosen lowest-priority device resident to
         the host tier (the inverse migration: contiguous KV demoted to
@@ -482,6 +493,18 @@ class Engine:
         slot = victim.slot
         self.lc.note_recomputed(victim)
         return slot
+
+    # --- waits on the host tier ---------------------------------------------
+    @contextlib.contextmanager
+    def _host_waiting(self):
+        """The engine thread blocks on the host tier: span
+        ``apex.host_wait``, its seconds counted in ``host_wait_time``."""
+        t = time.perf_counter()
+        try:
+            with span("host_wait"):
+                yield
+        finally:
+            self.stats.host_wait_time += time.perf_counter() - t
 
     # --- host-tier fault tolerance ------------------------------------------
     def _breaker_open(self) -> bool:
@@ -693,6 +716,10 @@ class Engine:
 
     # --- one engine iteration ------------------------------------------------
     def step(self) -> None:
+        with step_span(self.stats.iterations):
+            self._step()
+
+    def _step(self) -> None:
         if self._executor is not None and self.e.cold_page_compress_after > 0:
             # outside the timed section: compression is pool maintenance,
             # not iteration work the calibrator should learn from
@@ -704,42 +731,46 @@ class Engine:
                 # after t0 on purpose: the spike lands inside the timed
                 # section so the calibrator sees it like a real stall
                 time.sleep(spike)
-        self._apply_host_cancels()
-        admitted = self._admit()
-        self._rebalance()
-        # rows whose request already reached max_new_tokens (possible
-        # straight out of prefill when the clamp left room for exactly
-        # one token) must not ride this iteration's decode batch — they
-        # retire at the end of the step without over-generating.
-        # Chunked admissions still mid-prefill aren't decoding either.
-        active_rows = [i for i, r in enumerate(self.lc.slots)
-                       if r is not None and not r.done
-                       and r.phase is Phase.DECODE_DEVICE]
-        decision = self._schedule(admitted, active_rows)
-        plan = None
-        if self._chunked and self.lc.staging_order:
-            budget = (decision.chunk_tokens
-                      if decision is not None and self._sched_chunk_aware
-                      else self._fallback_chunk_budget(active_rows))
-            plan = self.lc.plan_chunks(budget)
-        tokens = np.zeros((self.e.device_slots,), np.int32)
-        for i in active_rows:
-            tokens[i] = self.lc.slots[i].output[-1]
-        # lengths hygiene for empty slots
-        mask = np.zeros((self.e.device_slots,), bool)
-        mask[active_rows] = True
-        lengths = jnp.where(jnp.asarray(mask), self.state.lengths, 0)
-        self.state = StackState(per_entry=self.state.per_entry,
-                                lengths=lengths)
+        with span("admit"):
+            self._apply_host_cancels()
+            admitted = self._admit()
+        with span("rebalance"):
+            self._rebalance()
+        with span("schedule"):
+            # rows whose request already reached max_new_tokens (possible
+            # straight out of prefill when the clamp left room for exactly
+            # one token) must not ride this iteration's decode batch — they
+            # retire at the end of the step without over-generating.
+            # Chunked admissions still mid-prefill aren't decoding either.
+            active_rows = [i for i, r in enumerate(self.lc.slots)
+                           if r is not None and not r.done
+                           and r.phase is Phase.DECODE_DEVICE]
+            decision = self._schedule(admitted, active_rows)
+            plan = None
+            if self._chunked and self.lc.staging_order:
+                budget = (decision.chunk_tokens
+                          if decision is not None and self._sched_chunk_aware
+                          else self._fallback_chunk_budget(active_rows))
+                plan = self.lc.plan_chunks(budget)
+        with span("dispatch"):
+            tokens = np.zeros((self.e.device_slots,), np.int32)
+            for i in active_rows:
+                tokens[i] = self.lc.slots[i].output[-1]
+            # lengths hygiene for empty slots
+            mask = np.zeros((self.e.device_slots,), bool)
+            mask[active_rows] = True
+            lengths = jnp.where(jnp.asarray(mask), self.state.lengths, 0)
+            self.state = StackState(per_entry=self.state.per_entry,
+                                    lengths=lengths)
 
-        cohort = self._ensure_cohort() if self.e.enable_offload else None
-        if cohort is not None:
-            wait = (decision is not None
-                    and decision.strategy == StrategyKind.ASYM_PIPELINE)
-            self._step_overlap(jnp.asarray(tokens), cohort, active_rows,
-                               wait=wait, plan=plan)
-        elif active_rows or plan is not None:
-            self._step_device_only(jnp.asarray(tokens), active_rows, plan)
+            cohort = self._ensure_cohort() if self.e.enable_offload else None
+            if cohort is not None:
+                wait = (decision is not None
+                        and decision.strategy == StrategyKind.ASYM_PIPELINE)
+                self._step_overlap(jnp.asarray(tokens), cohort, active_rows,
+                                   wait=wait, plan=plan)
+            elif active_rows or plan is not None:
+                self._step_device_only(jnp.asarray(tokens), active_rows, plan)
         if plan is not None:
             self.stats.prefill_chunks += len(plan.rows)
             self.stats.chunked_prefill_tokens += sum(plan.lens)
@@ -758,14 +789,16 @@ class Engine:
             if self._calibrator is not None:
                 self._calibrator.observe_step(predicted, dt)
                 self.stats.step_error_ewma = self._calibrator.step_error_ewma
-        self.lc.retire(free_host=(self._executor.free
-                                  if self._executor is not None
-                                  else lambda rid: None),
-                       publish=((lambda r: publish_retired(self, r))
-                                if self._prefix is not None else None))
+        with span("retire"):
+            self.lc.retire(free_host=(self._executor.free
+                                      if self._executor is not None
+                                      else lambda rid: None),
+                           publish=((lambda r: publish_retired(self, r))
+                                    if self._prefix is not None else None))
         # the cohort rebuilds itself at the next token boundary
         # (_ensure_cohort); completions always leave attn_ptr == -1
 
+    @spanned("sync")
     def _commit_device(self, logits, active_rows) -> None:
         toks = sample(logits[: self.e.device_slots],
                       temperature=self.e.temperature)
@@ -864,8 +897,9 @@ class Engine:
                         timeout = max(
                             self._pending_deadline - time.perf_counter(),
                             0.001)
-                    out = self._executor.result(self._pending_job,
-                                                timeout=timeout)
+                    with self._host_waiting():
+                        out = self._executor.result(self._pending_job,
+                                                    timeout=timeout)
                 else:
                     out = self._executor.poll(self._pending_job)
                     if out is None and self.e.recompute_fallback \
@@ -883,9 +917,10 @@ class Engine:
                 if not self.e.recompute_fallback:
                     raise
                 err = exc.__cause__
-                out = self._host_fallback(
-                    "timeout" if err is None
-                    else f"{type(err).__name__}: {err}"[:160])
+                with self._host_waiting():
+                    out = self._host_fallback(
+                        "timeout" if err is None
+                        else f"{type(err).__name__}: {err}"[:160])
                 fell_back = True
             if out is None:
                 host_idle = ctl.host_io(cohort)._replace(
@@ -950,9 +985,10 @@ class Engine:
             # in-flight cohort journeys finish exactly without trusting
             # a worker that just stalled or died
             idx = np.asarray(valid, np.int64)
-            out = self._executor.execute_sync(
-                next(self._job_ids), emit_layer, cohort.request_ids,
-                qkv.q, qkv.k, qkv.v, cohort.positions[idx], rows=idx)
+            with self._host_waiting():
+                out = self._executor.execute_sync(
+                    next(self._job_ids), emit_layer, cohort.request_ids,
+                    qkv.q, qkv.k, qkv.v, cohort.positions[idx], rows=idx)
             buf = np.zeros(cohort.attn_in.shape, np.float32)
             buf[idx] = out
             cohort.attn_in = jnp.asarray(buf)
@@ -994,22 +1030,23 @@ class Engine:
                 + self._pending_timeout
         cohort.x_carry = x_final[self.e.device_slots:]
         if completes:
-            row_idx = [self.e.device_slots + i for i in valid]
-            toks = np.asarray(sample(logits[jnp.asarray(row_idx)],
-                                     temperature=self.e.temperature))
-            emb = self.params.embedding["embed"]
-            for j, i in enumerate(valid):
-                r = self.lc.host_requests[cohort.slot_rids[i]]
-                r.output.append(int(toks[j]))
-                self.stats.host_tokens += 1
-                cohort.positions[i] += 1
-            # one stacked gather+scatter for the cohort's fresh
-            # embeddings (vs bc separate .at[i].set dispatches)
-            cohort.x_carry = cohort.x_carry.at[jnp.asarray(valid)].set(
-                jnp.take(emb, jnp.asarray(toks), axis=0
-                         ).astype(cohort.x_carry.dtype))
-            self._executor.advance_token(cohort.request_ids)
-            cohort.attn_in = jnp.zeros_like(cohort.attn_in)
+            with span("host_token"):
+                row_idx = [self.e.device_slots + i for i in valid]
+                toks = np.asarray(sample(logits[jnp.asarray(row_idx)],
+                                         temperature=self.e.temperature))
+                emb = self.params.embedding["embed"]
+                for j, i in enumerate(valid):
+                    r = self.lc.host_requests[cohort.slot_rids[i]]
+                    r.output.append(int(toks[j]))
+                    self.stats.host_tokens += 1
+                    cohort.positions[i] += 1
+                # one stacked gather+scatter for the cohort's fresh
+                # embeddings (vs bc separate .at[i].set dispatches)
+                cohort.x_carry = cohort.x_carry.at[jnp.asarray(valid)].set(
+                    jnp.take(emb, jnp.asarray(toks), axis=0
+                             ).astype(cohort.x_carry.dtype))
+                self._executor.advance_token(cohort.request_ids)
+                cohort.attn_in = jnp.zeros_like(cohort.attn_in)
         for rid in cohort.request_ids:
             self.lc.host_requests[rid].layer_progress = \
                 ctl.layer_progress(cohort)
@@ -1029,6 +1066,8 @@ class Engine:
         if self._executor is not None:
             self.stats.host_busy_time = self._executor.busy_time
             self.stats.host_transfer_time = self._executor.transfer_time
+            self.stats.host_device_wait_time = \
+                self._executor.device_wait_time
         self._refresh_host_pool_gauges()
         return self.stats
 

@@ -41,6 +41,13 @@ _ENGINE_METRICS: Dict[str, Tuple[str, str, str, Dict[str, str]]] = {
     "host_fallbacks": ("host_fallbacks_total", "counter",
                        "Host jobs abandoned by the watchdog and "
                        "recomputed exactly on the engine thread", {}),
+    "host_wait_seconds": ("host_wait_seconds_total", "counter",
+                          "Engine-thread seconds blocked waiting for "
+                          "host-attention jobs", {}),
+    "host_device_wait_seconds": ("host_device_wait_seconds_total",
+                                 "counter",
+                                 "Host-executor seconds waiting for the "
+                                 "device step that makes a job's QKV", {}),
     "host_breaker_trips": ("host_breaker_trips_total", "counter",
                            "Host-tier circuit-breaker trips (GPU_ONLY "
                            "pin for a cooldown)", {}),

@@ -230,6 +230,13 @@ class EngineStats:
     # the compute share feeds the calibrator's t_catt correction.
     host_busy_time: float = 0.0
     host_transfer_time: float = 0.0
+    # the part of the transfer spent waiting for the device step that
+    # makes a job's QKV (span apex.host_job.device_wait), and the
+    # engine thread's seconds blocked on the host tier: the
+    # ASYM_PIPELINE wait, breaker-open synchronous jobs and watchdog
+    # recomputes (span apex.host_wait)
+    host_device_wait_time: float = 0.0
+    host_wait_time: float = 0.0
     # jit traces taken by the bucketed/chunked prefill fast paths
     prefill_compilations: int = 0
     # chunked prefill: chunks executed, prompt tokens prefilled through
@@ -394,6 +401,8 @@ class EngineStats:
             "preemption_requeues": float(self.preemption_requeues),
             "preemption_recomputes": float(self.preemption_recomputes),
             "host_fallbacks": float(self.host_fallbacks),
+            "host_wait_seconds": self.host_wait_time,
+            "host_device_wait_seconds": self.host_device_wait_time,
             "host_breaker_trips": float(self.host_breaker_trips),
             "cancelled": float(self.cancelled),
             "degradation_level": float(

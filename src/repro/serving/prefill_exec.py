@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.overlap_engine import stack_row_kv_to_pool_layers
+from repro.core.spans import spanned
 from repro.models import init_decode_state, prefill
 from repro.models.kv_cache import StackState
 from repro.serving.lifecycle import pow2_ceil, transition
@@ -162,6 +163,7 @@ def prefill_to_host(eng, req: Request, host_slot: int) -> None:
     # the cohort picks the new member up at the next token boundary
 
 
+@spanned("finish_chunks")
 def finish_chunks(eng, plan, clogits) -> None:
     """Post-chunk bookkeeping for the chunked-prefill path: stream
     host-tier chunks' KV into the paged pool, and graduate completed

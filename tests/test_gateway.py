@@ -273,6 +273,8 @@ def test_health_and_metrics_endpoints(gateway_stack):
         families.add(line.split("{")[0].split(" ")[0])
     assert "apex_replica_up" in families
     assert "apex_engine_iterations_total" in families
+    assert "apex_engine_host_wait_seconds_total" in families
+    assert "apex_engine_host_device_wait_seconds_total" in families
     assert "apex_gateway_requests_total" in families
     # HELP/TYPE emitted exactly once per family
     helps = re.findall(r"# HELP (\S+)", metrics["body"])
