@@ -52,8 +52,24 @@ class Decision:
     predicted_time: float = 0.0
     # chunked-prefill plan: prefill tokens granted to this iteration's
     # fused chunk (0 = no chunk).  The mixed-branch timings above are
-    # evaluated at exactly this share, not the whole prompt backlog.
+    # evaluated at the chunk capacity, not at this grant: the device
+    # computes the whole chunk buffer whatever the grant.
     chunk_tokens: int = 0
+
+
+def chunk_budget(backlog: int, capacity: int) -> int:
+    """Prefill tokens granted to one chunk iteration: the whole backlog,
+    up to ``capacity`` (staging rows x ``chunk_tokens``, the most one
+    chunk call advances).
+
+    The chunk program's buffer is pinned at (staging rows,
+    ``pow2_ceil(chunk_tokens)``) so that KV is bit-identical however a
+    prompt is chunked (the prefix cache's contract), and the device
+    computes every position of it whatever the grant.  A smaller grant
+    therefore buys no step time; it only stretches prefill over more
+    iterations, each running the chunk program instead of decode alone.
+    """
+    return min(backlog, capacity)
 
 
 def _progress(req: Any) -> int:
@@ -78,31 +94,19 @@ class ApexScheduler:
     def schedule(self, prefill: Sequence[Any], decode_gpu: Sequence[Any],
                  decode_cpu: Sequence[Any], *, mean_context: float,
                  prefill_tokens: int = 0, chunk_backlog_tokens: int = 0,
-                 chunk_tokens_max: int = 0) -> Decision:
+                 chunk_capacity: int = 0) -> Decision:
         prefill = list(prefill)
         decode_gpu = list(decode_gpu)
         decode_cpu = list(decode_cpu)
 
         batch = max(len(decode_gpu), 1)
         chunk = 0
-        if chunk_tokens_max > 0 and chunk_backlog_tokens > 0:
-            # Chunked prefill: this iteration's fused chunk budget IS
-            # the mixed branch's prefill share — size it from the perf
-            # model (below) and evaluate rule 3 at that share.  An
-            # urgent prefill (elevated priority) takes the TTFT-first
-            # cap instead of the host-window-minimal chunk: shaving
-            # the chunk to the cohort's attention window would stretch
-            # an SLO-bound prompt over backlog/chunk extra iterations.
-            # A deadline alone does NOT trigger this — operators stamp
-            # loose default SLOs on whole workloads, and disabling the
-            # window sizing for all of them would silently cost the
-            # overlap efficiency the chunk rule exists to protect.
-            urgent = any(getattr(r, "priority", 0) > 0 for r in prefill)
-            chunk = self.chunk_budget(
-                len(decode_gpu), len(decode_cpu), mean_context,
-                backlog=chunk_backlog_tokens, cap=chunk_tokens_max,
-                urgent=urgent)
-            prefill_tokens = chunk
+        if chunk_capacity > 0 and chunk_backlog_tokens > 0:
+            # Chunked prefill: the fused chunk program computes its whole
+            # buffer whatever the grant, so rule 3 is evaluated at the
+            # capacity, not at the granted share.
+            chunk = chunk_budget(chunk_backlog_tokens, chunk_capacity)
+            prefill_tokens = chunk_capacity
         t = self.perf_model.timings(batch, mean_context,
                                     prefill_tokens=prefill_tokens)
         mixed = bool(prefill) and t.t_glinear_pref > 0.0
@@ -149,46 +153,6 @@ class ApexScheduler:
                         decode_cpu, reason="mixed Ineq fails",
                         predicted_time=self._aligned_time(t, mixed),
                         chunk_tokens=chunk)
-
-    # --- chunked-prefill budget ------------------------------------------
-    def chunk_budget(self, n_gpu: int, n_cpu: int, mean_context: float,
-                     *, backlog: int, cap: int,
-                     urgent: bool = False) -> int:
-        """Per-iteration prefill chunk budget (tokens).
-
-        With nothing decoding there is nothing to stall: grant the
-        whole backlog (TTFT-optimal, the pre-chunking behaviour).
-        With an active host cohort, pick the *smallest* power-of-two
-        chunk whose predicted mixed-iteration device time
-        (``t_glinear_pref + t_gatt_pref``) still covers the cohort's
-        one-layer host-attention time — the chunk keeps the
-        ASYNC_OVERLAP/ASYM_PIPELINE window wide enough that the host
-        job lands in-iteration (never late), while staying as small as
-        inter-token latency allows.  Device-only decode has no window
-        to protect, so the cap (the ``chunk_tokens`` knob) applies
-        directly.
-        """
-        if n_gpu == 0 and n_cpu == 0:
-            return backlog
-        budget = cap
-        if urgent:
-            # SLO-bound prefill: the cap (the operator's latency/
-            # throughput trade-off) applies directly — never shave
-            # below it for host-window overlap
-            return max(1, min(budget, backlog))
-        if n_cpu > 0:
-            t_catt = getattr(self.perf_model, "t_catt", None)
-            if t_catt is not None:
-                t_host = t_catt(n_cpu, mean_context, layers=1)
-                c = 1
-                while c < cap:
-                    t = self.perf_model.timings(max(n_gpu, 1), mean_context,
-                                                prefill_tokens=c)
-                    if t.t_glinear_pref + t.t_gatt_pref >= t_host:
-                        break
-                    c <<= 1
-                budget = min(c, cap)
-        return max(1, min(budget, backlog))
 
     # --- predicted iteration times (Eqs. 1/2 + mixed variants) ----------
     @staticmethod

@@ -123,9 +123,9 @@ def main() -> None:
                          "(0 = off); pages decompress transparently "
                          "on touch")
     ap.add_argument("--chunk-tokens", type=int, default=64,
-                    help="chunked-prefill budget per iteration while "
-                         "decode is active (0 = whole-prompt prefill "
-                         "before decode)")
+                    help="chunked prefill: prompt tokens each "
+                         "prefilling request advances per iteration "
+                         "(0 = whole-prompt prefill before decode)")
     ap.add_argument("--no-offload", action="store_true")
     ap.add_argument("--no-prefix-cache", action="store_true",
                     help="disable the cross-request prefix cache "

@@ -62,12 +62,12 @@ class ServerConfig:
     # docs/serving_api.md "Host KV precision and compression"
     host_kv_dtype: str = "fp32"
     cold_page_compress_after: float = 0.0
-    # chunked prefill co-scheduled with decode: per-iteration prompt
-    # token budget while decode is active (the scheduler may grant
-    # less, sizing the chunk to the host-attention window, or the
-    # whole backlog when nothing decodes); 0 = whole-prompt prefill
-    # before decode (the pre-chunking behaviour).  See
-    # docs/serving_api.md "Chunked prefill".
+    # chunked prefill co-scheduled with decode: each prefilling row's
+    # prompt tokens per iteration, and the width of the chunk buffer
+    # (every chunk iteration fills it, up to staging rows x
+    # chunk_tokens); 0 = whole-prompt prefill before decode (the
+    # pre-chunking behaviour).  See docs/serving_api.md "Chunked
+    # prefill".
     chunk_tokens: int = 64
     # --- request lifecycle (docs/serving_api.md "Request lifecycle,
     # migration, and SLOs") -----------------------------------------

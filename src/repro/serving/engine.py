@@ -49,7 +49,7 @@ from repro.core.overlap_engine import (Cohort, HostExecutor,
                                        stack_row_kv_to_pool_layers)
 from repro.core.perf_model import OnlineCalibrator, resolve_perf_model
 from repro.core.scheduler import (AdmissionController, ApexScheduler,
-                                  Decision, StrategyKind)
+                                  Decision, StrategyKind, chunk_budget)
 from repro.core.spans import span, spanned, step_span
 from repro.distributed.fault_tolerance import RestartPolicy
 from repro.serving.faults import FaultInjector
@@ -113,7 +113,7 @@ class Engine:
                 max_pipeline_sub_batch=self.e.max_pipeline_sub_batch)
         if self.scheduler is not None:
             import inspect
-            self._sched_chunk_aware = "chunk_tokens_max" in \
+            self._sched_chunk_aware = "chunk_capacity" in \
                 inspect.signature(self.scheduler.schedule).parameters
         device_budget = (self.e.device_kv_budget_tokens
                          if self.e.device_kv_budget_tokens is not None
@@ -678,16 +678,14 @@ class Engine:
             self.lc.schedule_snapshots(admitted, active_rows,
                                        chunked=self._chunked)
         if self._chunked:
-            # chunk-aware scheduler: the granted budget IS the mixed
-            # branch's prefill share (computed inside schedule()).  A
-            # legacy injected scheduler never sees the chunk kwargs, so
-            # approximate the share it should price in with the same
-            # fallback budget step() will actually grant — otherwise
-            # predicted_time omits the chunk work and skews the
+            # a chunk-aware scheduler prices the chunk inside schedule().
+            # A legacy injected scheduler never sees the chunk kwargs, so
+            # hand it the chunk capacity the device will compute —
+            # otherwise predicted_time omits the chunk work and skews the
             # calibrator low on every staging iteration.
-            prefill_tokens = 0 if self._sched_chunk_aware else (
-                min(backlog, self._fallback_chunk_budget(active_rows))
-                if prefill_q else 0)
+            prefill_tokens = (self._chunk_capacity()
+                              if prefill_q and not self._sched_chunk_aware
+                              else 0)
         else:
             prefill_tokens = sum(r.prompt_len for r in admitted)
         if not (prefill_q or decode_gpu or decode_cpu):
@@ -697,8 +695,8 @@ class Engine:
         kw = {}
         if self._sched_chunk_aware:
             kw = dict(chunk_backlog_tokens=backlog,
-                      chunk_tokens_max=(self.e.chunk_tokens
-                                        if self._chunked else 0))
+                      chunk_capacity=(self._chunk_capacity()
+                                      if self._chunked else 0))
         decision = self.scheduler.schedule(
             prefill_q, decode_gpu, decode_cpu,
             mean_context=max(mean_context, 1.0),
@@ -707,12 +705,11 @@ class Engine:
         return decision
 
     # --- chunked-prefill planning -------------------------------------------
-    def _fallback_chunk_budget(self, active_rows: List[int]) -> int:
-        """Chunk budget when no scheduler is wired: the whole backlog
-        while nothing decodes, the knob's cap otherwise."""
-        if not active_rows and not self.lc.decoding_hosts():
-            return self.lc.staging_backlog()
-        return self.e.chunk_tokens
+    def _chunk_capacity(self) -> int:
+        """The most one chunk call advances: ``chunk_tokens`` on every
+        staging row, which is what the fused chunk program's pinned
+        buffer holds (``plan_chunks``)."""
+        return len(self.lc.staging) * self.e.chunk_tokens
 
     # --- one engine iteration ------------------------------------------------
     def step(self) -> None:
@@ -750,7 +747,8 @@ class Engine:
             if self._chunked and self.lc.staging_order:
                 budget = (decision.chunk_tokens
                           if decision is not None and self._sched_chunk_aware
-                          else self._fallback_chunk_budget(active_rows))
+                          else chunk_budget(self.lc.staging_backlog(),
+                                            self._chunk_capacity()))
                 plan = self.lc.plan_chunks(budget)
         with span("dispatch"):
             tokens = np.zeros((self.e.device_slots,), np.int32)
@@ -774,6 +772,8 @@ class Engine:
         if plan is not None:
             self.stats.prefill_chunks += len(plan.rows)
             self.stats.chunked_prefill_tokens += sum(plan.lens)
+            self.stats.chunk_iterations += 1
+            self.stats.chunk_buffer_tokens += plan.tokens.size
             if active_rows or cohort is not None:
                 self.stats.chunk_co_run_iterations += 1
             self.stats.prefill_compilations = self._prefill_compiles

@@ -244,6 +244,10 @@ class EngineStats:
     prefill_chunks: int = 0
     chunked_prefill_tokens: int = 0
     chunk_co_run_iterations: int = 0
+    # iterations whose step ran the chunk program (fused or alone), and
+    # the token positions of the chunk buffer those calls computed
+    chunk_iterations: int = 0
+    chunk_buffer_tokens: int = 0
     # --- tier rebalancing / SLO admission ---------------------------
     # host→device promotions (including mid-prefill retargets) and
     # device→host demotions executed by the engine

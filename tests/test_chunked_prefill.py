@@ -16,7 +16,7 @@ import pytest
 from repro.configs import get_config
 from repro.models import init_decode_state, init_params, prefill, prefill_chunk
 from repro.serving.engine import Engine, EngineConfig
-from repro.serving.request import Request
+from repro.serving.request import Phase, Request
 
 
 def _dense_cfg():
@@ -151,6 +151,134 @@ def test_recurrent_archs_ride_chunked_prefill():
                                            chunk_tokens=16))
     assert eng._chunked is True
     eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# Chunk grant under a live host cohort: the pinned buffer fills
+# ---------------------------------------------------------------------------
+
+_CHUNK = 8
+_STAGED = (20, 13, 30)          # prompts staged while the cohort decodes
+
+
+def _host_cohort_scenario():
+    """One device row and one host row decoding, then three host-tier
+    prompts staged beside them.  Returns the requests, the engine's
+    stats, the chunk iterations before the staging, and per chunk
+    iteration after it {request: (consumed before, after)} for every
+    staged prompt with prompt left, whether the host cohort was
+    decoding, and whether it emitted a token."""
+    cfg = _dense_cfg()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(11)
+    eng = Engine(cfg, params, EngineConfig(
+        device_slots=1, host_slots=4, cache_len=128,
+        chunk_tokens=_CHUNK, host_job_min_timeout=600.0))
+    first = [Request(prompt=list(rng.integers(0, cfg.vocab_size, n)),
+                     max_new_tokens=out) for n, out in ((6, 60), (5, 40))]
+    staged = [Request(prompt=list(rng.integers(0, cfg.vocab_size, n)),
+                      max_new_tokens=6) for n in _STAGED]
+    steps = []
+    try:
+        for r in first:
+            eng.submit(r)
+        while not (first[1].phase is Phase.DECODE_HOST
+                   and eng.stats.host_tokens > 0):
+            eng.step()
+        assert first[0].phase is Phase.DECODE_DEVICE
+        chunks0 = eng.stats.chunk_iterations
+        for r in staged:
+            eng.submit(r)
+        while eng.has_work:
+            before = {r.request_id: _consumed(eng, r) for r in staged}
+            chunks, host = eng.stats.chunk_iterations, eng.stats.host_tokens
+            hosts_decoding = bool(eng.lc.decoding_hosts())
+            eng.step()
+            if eng.stats.chunk_iterations > chunks:
+                advanced = {r.request_id: (before[r.request_id],
+                                           _consumed(eng, r))
+                            for r in staged
+                            if before[r.request_id] < r.prompt_len}
+                steps.append((advanced, hosts_decoding,
+                              eng.stats.host_tokens > host))
+        return first + staged, eng.stats, chunks0, steps
+    finally:
+        eng.shutdown()
+
+
+def _consumed(eng, req):
+    """Prompt tokens of ``req`` prefilled so far."""
+    if req.phase is Phase.QUEUED:
+        return 0
+    for row in eng.lc.staging_order:
+        if eng.lc.staging[row].req is req:
+            return eng.lc.staging[row].consumed
+    return req.prompt_len
+
+
+@pytest.fixture(scope="module")
+def host_cohort_run():
+    return _host_cohort_scenario()
+
+
+def test_chunk_iterations_advance_every_row_by_chunk_tokens(host_cohort_run):
+    """With a host cohort decoding, every chunk iteration advances each
+    staged row with prompt left by min(remaining, chunk_tokens): the
+    grant fills the pinned buffer instead of trickling 1-2 tokens."""
+    reqs, _, _, steps = host_cohort_run
+    prompt_len = {r.request_id: r.prompt_len for r in reqs}
+    staged_steps = [s for s in steps if s[0]]
+    assert staged_steps
+    for advanced, hosts_decoding, _ in staged_steps:
+        assert hosts_decoding
+        assert len(advanced) >= 1
+        for rid, (b, a) in advanced.items():
+            assert a - b == min(prompt_len[rid] - b, _CHUNK), (rid, b, a)
+
+
+def test_prompt_completes_in_ceil_p_over_chunk_iterations(host_cohort_run):
+    """A P-token prompt staged under a live cohort prefills in exactly
+    ceil(P / chunk_tokens) chunk iterations, and the cohort keeps
+    emitting while the prompts prefill."""
+    reqs, _, _, steps = host_cohort_run
+    for r in reqs[2:]:
+        n = sum(1 for advanced, _, _ in steps if r.request_id in advanced)
+        assert n == -(-r.prompt_len // _CHUNK), (r.prompt_len, n)
+        assert r.output
+    staging_iters = -(-max(_STAGED) // _CHUNK)
+    assert any(emitted for _, _, emitted in steps[:staging_iters])
+
+
+def test_full_buffer_grant_tokens_identical_to_whole_prompt(host_cohort_run):
+    """The same requests through the whole-prompt engine (no chunking)
+    emit the same greedy tokens."""
+    reqs, _, _, _ = host_cohort_run
+    cfg = _dense_cfg()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    legacy = Engine(cfg, params, EngineConfig(
+        device_slots=1, host_slots=4, cache_len=128, chunk_tokens=0,
+        host_job_min_timeout=600.0))
+    ref = _clone(reqs)
+    try:
+        legacy.run(ref)
+    finally:
+        legacy.shutdown()
+    for x, y in zip(ref, reqs):
+        assert x.output == y.output
+
+
+def test_chunk_counters(host_cohort_run):
+    """``chunk_iterations`` counts every iteration that ran the chunk
+    program; ``chunk_buffer_tokens`` the buffer positions it computed
+    (staging rows x pow2_ceil(chunk_tokens) a call), so their ratio
+    with ``chunked_prefill_tokens`` is the buffer's fill."""
+    reqs, stats, chunks0, steps = host_cohort_run
+    assert chunks0 >= 1 and steps
+    assert stats.chunk_iterations == chunks0 + len(steps)
+    rows = 1 + 4                              # device + host slots
+    assert stats.chunk_buffer_tokens == stats.chunk_iterations * rows * _CHUNK
+    assert stats.chunked_prefill_tokens == sum(r.prompt_len for r in reqs)
+    assert stats.chunked_prefill_tokens < stats.chunk_buffer_tokens
 
 
 # ---------------------------------------------------------------------------

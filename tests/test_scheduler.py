@@ -6,7 +6,7 @@ from hypothesis import given, settings, strategies as st
 from repro.configs import get_config
 from repro.core.perf_model import analytic_model
 from repro.core.scheduler import (AdmissionController, ApexScheduler,
-                                  StrategyKind)
+                                  StrategyKind, chunk_budget)
 from repro.models.kv_cache import PagedKVPool
 
 
@@ -68,41 +68,65 @@ def test_host_min_ratio_above_threshold_still_pipelines():
 
 
 def test_chunk_budget_idle_grants_whole_backlog(sched):
-    """Nothing decoding => nothing to stall: the whole prompt backlog
-    prefills at once (the TTFT-optimal admission-burst path)."""
-    assert sched.chunk_budget(0, 0, 1024, backlog=777, cap=64) == 777
+    """Nothing decoding => nothing to stall: a backlog that fits one
+    chunk call prefills at once (the admission-burst path)."""
+    d = sched.schedule(["p"], [], [], mean_context=1,
+                       chunk_backlog_tokens=777, chunk_capacity=16 * 64)
+    assert d.chunk_tokens == chunk_budget(777, 16 * 64) == 777
 
 
 def test_chunk_budget_caps_under_active_decode(sched):
-    """Device-only decode active: the knob's cap bounds the chunk (and
-    the backlog bounds it from below when smaller)."""
-    assert sched.chunk_budget(4, 0, 1024, backlog=10_000, cap=64) == 64
-    assert sched.chunk_budget(4, 0, 1024, backlog=5, cap=64) == 5
+    """Device-only decode active: the chunk capacity bounds the grant,
+    and the backlog bounds it from below when smaller."""
+    for backlog, want in ((10_000, 256), (256, 256), (5, 5)):
+        d = sched.schedule(["p"], list(range(4)), [], mean_context=1024,
+                           chunk_backlog_tokens=backlog, chunk_capacity=256)
+        assert d.chunk_tokens == want
 
 
-def test_chunk_budget_targets_host_window(sched):
-    """With a live host cohort the chunk is the smallest power of two
-    whose predicted mixed-iteration device time covers the cohort's
-    one-layer host attention — never above the cap."""
-    c = sched.chunk_budget(4, 8, 1024, backlog=10_000, cap=256)
-    assert 1 <= c <= 256 and (c & (c - 1)) == 0
-    t = sched.perf_model.timings(4, 1024, prefill_tokens=c)
-    t_host = sched.perf_model.t_catt(8, 1024, layers=1)
-    # the window covers the host job (or the cap bound)
-    assert t.t_glinear_pref + t.t_gatt_pref >= t_host or c == 256
+@pytest.mark.parametrize("backlog", [1, 2, 63, 64, 500, 1024, 10_000])
+def test_chunk_budget_fills_buffer_under_host_cohort(sched, backlog):
+    """With a live host cohort the grant is min(backlog, capacity): the
+    chunk program computes its whole pinned buffer whatever the grant,
+    so the grant is never shaved to the host-attention window."""
+    capacity = 16 * 64
+    d = sched.schedule(["p"], list(range(8)), list(range(8)),
+                       mean_context=1024, chunk_backlog_tokens=backlog,
+                       chunk_capacity=capacity)
+    assert d.decode_cpu                       # the host cohort is live
+    assert d.chunk_tokens == min(backlog, capacity)
 
 
 def test_decision_carries_chunk_tokens(sched):
-    """schedule() with a chunk backlog evaluates the mixed branch at
-    the granted chunk and surfaces it in Decision.chunk_tokens."""
+    """schedule() with a chunk backlog surfaces the grant in
+    Decision.chunk_tokens."""
     d = sched.schedule(["p"], list(range(8)), [], mean_context=256,
-                       chunk_backlog_tokens=500, chunk_tokens_max=32)
+                       chunk_backlog_tokens=500, chunk_capacity=32)
     assert d.chunk_tokens == 32
     assert d.strategy == StrategyKind.GPU_ONLY     # no host rows
     # legacy call path keeps chunk_tokens at 0
     d2 = sched.schedule(["p"], list(range(8)), [], mean_context=256,
                         prefill_tokens=500)
     assert d2.chunk_tokens == 0
+
+
+@pytest.mark.parametrize("host_rows", [0, 8])
+def test_mixed_branch_priced_at_chunk_buffer(sched, host_rows):
+    """A 2-token grant into a 1024-token buffer is priced as the 1024
+    tokens the device computes, not as 2: predicted_time matches a
+    whole-buffer prefill share on both the GPU-only and the host-cohort
+    branches."""
+    kw = dict(mean_context=1024)
+    d = sched.schedule(["p"], list(range(8)), list(range(host_rows)),
+                       chunk_backlog_tokens=2, chunk_capacity=1024, **kw)
+    full = sched.schedule(["p"], list(range(8)), list(range(host_rows)),
+                          prefill_tokens=1024, **kw)
+    assert d.chunk_tokens == 2
+    assert d.strategy == full.strategy
+    assert d.predicted_time == pytest.approx(full.predicted_time)
+    t2 = sched.schedule(["p"], list(range(8)), list(range(host_rows)),
+                        prefill_tokens=2, **kw).predicted_time
+    assert d.predicted_time > t2
 
 
 def test_rule4_partial_progress_prioritized(sched):
