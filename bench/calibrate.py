@@ -46,7 +46,7 @@ def main(argv=None) -> int:
     out = open(args.out, "a") if args.out else None
     for seed in [int(s) for s in args.seeds.split(",")]:
         t0 = time.perf_counter()
-        run = harness.serve_window(cfgfile, mix, seed, args.seconds,
+        run = harness.serve_window(registry, cfgfile, mix, seed, args.seconds,
                                    t_process=t0)
         t1 = time.perf_counter()
         prog = harness.check(registry, cfgfile, run.client, seed, run.failed)

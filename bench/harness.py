@@ -18,11 +18,10 @@ import sys
 import tempfile
 import threading
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from bench import flops
 from bench.registry import ROOT, Registry
 from bench.traffic import Spec, Traffic
 from bench.window import StepRecord, Window
@@ -74,24 +73,6 @@ class CompileCounter:
             return self.requests, self.loads
 
 
-def model_config(c: Dict):
-    """The serving engine's ``ModelConfig`` for a configuration file."""
-    from repro.models.config import BlockKind, FFNKind, ModelConfig
-
-    m = c["model"]
-    if m.get("tie_word_embeddings") or m.get("bias"):
-        raise ValueError(f"{c['name']}: tied embeddings or biases are not "
-                         f"served by this harness")
-    return ModelConfig(
-        name=c["name"], num_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"],
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        block_pattern=(BlockKind.ATTN,), ffn_kind=FFNKind.DENSE,
-        rope_theta=float(m["rope_theta"]), norm_eps=float(m["rms_norm_eps"]),
-        max_seq_len=m["max_position_embeddings"])
-
-
 def server_config(c: Dict, mcfg):
     """``ServerConfig`` from the configuration's engine geometry; the host
     pool holds every host slot at full ``cache_len``."""
@@ -119,11 +100,15 @@ class Client:
     """The benchmark's side of the server: submits requests and watches
     their outputs grow."""
 
-    def __init__(self, server, traffic: Traffic) -> None:
+    def __init__(self, server, traffic: Traffic,
+                 step_counters: Sequence[str] = ()) -> None:
         from jax.profiler import TraceAnnotation
 
         self.server = server
         self.traffic = traffic
+        # engine counters whose change in each recorded step goes into
+        # its StepRecord (the family's STEP_COUNTERS)
+        self.step_counters = tuple(step_counters)
         self.span = TraceAnnotation
         self.reqs: Dict[int, object] = {}      # every request sent
         self.active: Dict[int, object] = {}    # not yet finished
@@ -176,6 +161,8 @@ class Client:
 
         stats = self.server.engine.stats
         before = counters(stats) if self.recording is not None else None
+        watched = self.step_counters if self.recording is not None else ()
+        watch0 = [getattr(stats, k) for k in watched]
         gc0, t_start = self._gc_s, time.perf_counter()
         with self.span("bench.step"):
             self.server.step()
@@ -210,7 +197,9 @@ class Client:
             if self.recording is not None:
                 self.recording.append(StepRecord(
                     t_end=now, device_contexts=contexts, host_tokens=host_new,
-                    prompt_tokens=int(prompt - self._prompt_seen)))
+                    prompt_tokens=int(prompt - self._prompt_seen),
+                    counters={k: float(getattr(stats, k) - v0)
+                              for k, v0 in zip(watched, watch0)}))
             self._prompt_seen = prompt
 
     def drive_until(self, done: Callable[[], bool], limit: float, *,
@@ -267,21 +256,21 @@ class RunResult:
     failed: int
 
 
-def serve_window(cfgfile: Dict, mix: Dict, seed: int, seconds: float, *,
-                 t_process: float, trace_dir: Optional[str] = None,
-                 log=print) -> RunResult:
+def serve_window(registry: Registry, cfgfile: Dict, mix: Dict, seed: int,
+                 seconds: float, *, t_process: float,
+                 trace_dir: Optional[str] = None, log=print) -> RunResult:
     """Set up, warm up and measure one window; the server is shut down
     and its device memory freed before this returns."""
     import jax
 
     from repro.serving import InferenceServer
-    from bench.weights import program_params
 
     compiles = CompileCounter()
-    mcfg = model_config(cfgfile)
+    fam = registry.family_of(cfgfile)
+    mcfg = fam.model_config(cfgfile)
     engine = cfgfile["engine"]
-    params = program_params(seed, cfgfile["model"])
-    log(f"weights made: {flops.param_count(cfgfile['model']) / 1e9:.3f}B "
+    params = fam.program_params(seed, cfgfile["model"])
+    log(f"weights made: {fam.param_count(cfgfile['model']) / 1e9:.3f}B "
         f"parameters, {time.perf_counter() - t_process:.2f}s since start")
     server = InferenceServer(mcfg, params, server_config(cfgfile, mcfg))
     client = None
@@ -289,7 +278,7 @@ def serve_window(cfgfile: Dict, mix: Dict, seed: int, seconds: float, *,
         traffic = Traffic(mix, seed, vocab=mcfg.vocab_size,
                           cache_len=engine["cache_len"],
                           streams=engine["device_slots"] + engine["host_slots"])
-        client = Client(server, traffic)
+        client = Client(server, traffic, fam.STEP_COUNTERS)
         warm_up(client, seed, engine, mcfg.vocab_size)
         c0 = counters(server.stats)
         k0 = compiles.mark()
@@ -323,7 +312,7 @@ def serve_window(cfgfile: Dict, mix: Dict, seed: int, seconds: float, *,
             t0=t0, t1=t1, setup_s=setup_s, model=cfgfile["model"],
             engine=engine, stamps=client.stamps,
             token_tiers=client.token_tiers, failed=failed, counters0=c0,
-            counters1=c1, steps=steps)
+            counters1=c1, steps=steps, family=fam)
         return RunResult(window=window, client=client,
                          compiles_in_window=(k1[0] - k1[1]) - (k0[0] - k0[1]),
                          cache_loads_in_window=k1[1] - k0[1],
@@ -422,7 +411,8 @@ def check(registry: Registry, cfgfile: Dict, client: Client, seed: int,
     host += [[]] * pad
     ref = registry.reference(cfgfile["reference"])
     g = ref.logit_gaps(seed, cfgfile["model"], seqs,
-                       cfgfile["engine"]["cache_len"], control=control,
+                       cfgfile["engine"]["cache_len"],
+                       family=registry.family_of(cfgfile), control=control,
                        host=host)
     compared = {
         "mean_logit_gap": {"value": g["mean"], "limit": lim["mean_logit_gap"]},

@@ -1,103 +1,48 @@
 """Put the device's idle time down to the serving program's own spans.
 
-    python3 bench/idle_by_span.py --workload <cell> --seed <n> --seconds 51 \
-        [--out result.json]
+    python3 bench/idle_by_span.py --workload <cell> --seed <n> --seconds 51
 
-Serves one traced window of the cell as ``bench/run.py --trace 1`` does,
-then reduces the trace twice: with ``trace_reduce`` (busy and window
-seconds, from the benchmark's ``bench.*`` spans) and here, where every
-idle interval of the window is split at the edges of the spans on the
-engine's thread (the host line that holds the ``bench.step`` spans) and
-each piece is credited to the innermost span covering it, or to
-``no span``.  Prints one JSON object: idle seconds by span, the idle
-shares of the program's layers, the longest idle gaps with their pieces,
-and the cell's end-to-end and per-layer metrics as a run reads them.
+serves one traced window of the cell, as ``bench/run.py --trace 1`` does:
+it prints the idle seconds by span, the longest idle gaps with their
+pieces, and a result line whose metrics hold the layers' idle shares.
 
-The program names its spans ``apex.<phase>`` (``repro.core.spans``);
-spans on other lines, such as the host-attention workers', never label
-device idle.
+``trace_reduce.reduce`` calls ``attribute`` on every traced window: each
+idle interval is split at the edges of the spans on the engine's thread
+(the host line that holds the ``bench.step`` spans) and each piece is
+credited to the innermost span covering it, or to ``no span``.  The
+program names its spans ``apex.<phase>`` (``repro.core.spans``); spans on
+other lines, such as the host-attention workers', never label device
+idle.
+
+A layer's idle share is a reader of its own,
+``metrics/<layer>.idle_share.py``, that names the layer's spans and reads
+them with ``idle_share``.
 """
 from __future__ import annotations
 
-import time
+import bisect
+from collections import defaultdict
+from typing import Dict, List, Optional, Sequence, Tuple
 
-T_PROCESS = time.perf_counter()
-
-import argparse  # noqa: E402
-import bisect  # noqa: E402
-import json  # noqa: E402
-import shutil  # noqa: E402
-import sys  # noqa: E402
-from collections import defaultdict  # noqa: E402
-from pathlib import Path  # noqa: E402
-from typing import Dict, List, Sequence, Tuple  # noqa: E402
-
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-from bench import trace_reduce as tr  # noqa: E402
-
-PROGRAM_PREFIX = "apex."
 NO_SPAN = "no span"
-# each layer's idle share and the program's spans it reads; idle under
-# a host job on the engine thread (a synchronous recompute) is a wait
-# for the host tier
-SHARES = {
-    "lifecycle.idle_share": ("apex.admit", "apex.rebalance",
-                             "apex.tier_move", "apex.retire"),
-    "sched.idle_share": ("apex.schedule",),
-    "engine.idle_share": ("apex.step", "apex.dispatch", "apex.sync",
-                          "apex.host_token", "apex.finish_chunks"),
-    "host.wait_idle_share": ("apex.host_wait", "apex.host_job",
-                             "apex.host_job.device_wait",
-                             "apex.host_job.copy", "apex.host_job.attention"),
-}
-# idle under the benchmark's own spans or under none
-REST = "rest.idle_share"
 
-Span = Tuple[str, float, float, int]   # (name, start_ns, end_ns, line)
+Span = Tuple[str, float, float]   # (name, start_ns, end_ns)
 
 
-def span_name(name: str) -> str:
-    """A span's name without the ``#key=value#`` metadata a profiler may
-    append to it."""
-    return name.split("#", 1)[0]
-
-
-def load(path: str) -> Dict:
-    """``trace_reduce.load``'s events, and every ``bench.*`` and
-    ``apex.*`` span of the host planes with the line (thread) it is on,
-    numbered in the order the trace lists them."""
-    from jax.profiler import ProfileData
-
-    events = tr.load(path)
-    lines: List[Span] = []
-    n = 0
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            lines += [(span_name(e.name), e.start_ns, e.end_ns, n)
-                      for e in line.events
-                      if e.name.startswith((tr.SPAN_PREFIX, PROGRAM_PREFIX))]
-            n += 1
-    events["lines"] = lines
-    return events
-
-
-def engine_spans(events: Dict) -> List[Tuple[str, float, float]]:
-    """The spans of the line that holds the ``bench.step`` spans."""
-    steps = defaultdict(int)
-    for name, _, _, line in events["lines"]:
+def engine_spans(lines: Sequence[Tuple[str, float, float, int]]) -> List[Span]:
+    """The spans of the line that holds the most ``bench.step`` spans;
+    ``lines`` are (name, start_ns, end_ns, line) of every host span."""
+    steps: Dict[int, int] = defaultdict(int)
+    for name, _, _, line in lines:
         if name == "bench.step":
             steps[line] += 1
     if not steps:
         return []
     engine = max(steps, key=steps.get)
-    return [(name, a, b) for name, a, b, line in events["lines"]
-            if line == engine]
+    return [(name, a, b) for name, a, b, line in lines if line == engine]
 
 
-def segments(spans: Sequence[Tuple[str, float, float]], lo: float,
+def segments(spans: Sequence[Span], lo: float,
              hi: float) -> List[Tuple[float, float, str]]:
     """[lo, hi] cut at every span edge, each piece labelled with the
     shortest (innermost) span covering it, or ``no span``."""
@@ -129,36 +74,27 @@ def split(gap: Tuple[float, float],
     return out
 
 
-def attribute(events: Dict, top: int = 8) -> Dict:
-    """Idle seconds of the traced window by the innermost engine-thread
-    span, averaged over the devices that ran anything, as ``busy_s`` is;
-    the window is ``trace_reduce``'s, from the ``bench.*`` spans alone."""
-    spans = events["spans"]
-    if not spans:
-        raise ValueError("the trace holds no benchmark spans")
-    lo = min(a for _, a, _ in spans)
-    hi = max(b for _, _, b in spans)
-    segs = segments(engine_spans(events), lo, hi)
+def attribute(spans: Sequence[Span],
+              device_gaps: Sequence[Sequence[Tuple[float, float]]],
+              lo: float, hi: float, top: int = 8) -> Dict:
+    """Idle seconds of the window [lo, hi] (ns) by the innermost of the
+    engine line's ``spans``, averaged over the devices (one list of idle
+    gaps each, as ``trace_reduce.gaps`` gives them), largest first; and
+    the ``top`` longest gaps with their pieces."""
+    segs = segments(spans, lo, hi)
     ends = [b for _, b, _ in segs]
     by_span: Dict[str, float] = defaultdict(float)
     longest = []
-    n_dev = 0
-    for dev in events["devices"].values():
-        ops = tr.clip([(a, b) for _, a, b in dev["ops"]], lo, hi)
-        if not ops:
-            continue
-        n_dev += 1
-        for gap in tr.gaps(tr.union(ops), lo, hi):
+    for dev_gaps in device_gaps:
+        for gap in dev_gaps:
             pieces = split(gap, segs, ends)
             for name, ns in pieces.items():
                 by_span[name] += ns
             longest.append((gap[1] - gap[0], gap[0] - lo, pieces))
-    if not n_dev:
-        raise ValueError("no operation ran on a device in the traced window")
     ns = 1e-9
     longest.sort(key=lambda g: -g[0])
     return {
-        "idle_by_span": {k: v * ns / n_dev for k, v in
+        "idle_by_span": {k: v * ns / len(device_gaps) for k, v in
                          sorted(by_span.items(), key=lambda kv: -kv[1])},
         "longest_gaps": [
             {"s": d * ns, "at_s": at * ns,
@@ -168,72 +104,21 @@ def attribute(events: Dict, top: int = 8) -> Dict:
     }
 
 
-def layer_shares(idle_by_span: Dict[str, float],
-                 window_s: float) -> Dict[str, float]:
-    """Idle under each layer's spans as a share (%) of the window, and
-    the rest: idle under ``bench.*`` spans and under no span."""
-    out = {share: 100.0 * sum(idle_by_span.get(n, 0.0)
-                              for n in names) / window_s
-           for share, names in SHARES.items()}
-    named = {n for names in SHARES.values() for n in names}
-    out[REST] = 100.0 * sum(
-        v for k, v in idle_by_span.items() if k not in named) / window_s
-    return out
-
-
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, default=51.0)
-    ap.add_argument("--out", default=None)
-    args = ap.parse_args(argv)
-
-    import jax
-
-    from bench import harness
-    from bench.peaks import peak_for
-    from bench.registry import Registry, read_metric
-
-    if jax.devices()[0].platform != "tpu":
-        print("idle_by_span: needs a TPU", file=sys.stderr)
-        return 2
-    harness.configure_jax()
-    registry = Registry()
-    cell = registry.cell(args.workload)
-    trace_dir = harness.new_trace_dir()
-    try:
-        run = harness.serve_window(
-            registry.config(cell["config"]), registry.mix(cell["traffic"]),
-            args.seed, args.seconds, t_process=T_PROCESS,
-            trace_dir=trace_dir)
-        events = load(tr.find_xplane(trace_dir))
-    finally:
-        shutil.rmtree(trace_dir, ignore_errors=True)
-    w = run.window
-    w.peak = peak_for(jax.devices()[0].device_kind)
-    w.trace = tr.reduce(events)
-    row = {"cell": cell["name"], "seed": args.seed,
-           "busy_s": w.trace["busy_s"], "window_s": w.trace["window_s"],
-           "device_idle_s": w.trace["window_s"] - w.trace["busy_s"],
-           "iterations": w.delta("iterations"),
-           "metrics": {}}
-    for kind in ("end_to_end", "per_layer"):
-        for entry in registry.metric_entries(cell["name"], kind):
-            m = read_metric(registry, entry, w)
-            if m is not None:
-                row["metrics"][entry["name"]] = m["value"]
-    row.update(attribute(events))
-    row["layer_shares"] = layer_shares(row["idle_by_span"],
-                                       w.trace["window_s"])
-    row["notes"] = run.notes
-    line = json.dumps(row)
-    print(line)
-    if args.out:
-        with open(args.out, "a") as f:
-            f.write(line + "\n")
-    return 0
+def idle_share(trace: Optional[Dict], names: Sequence[str]) -> Optional[float]:
+    """Idle under the spans ``names`` as a share (%) of the traced window;
+    None without a trace, or where the engine's line holds no program
+    span (a program without spans has nothing to read)."""
+    if trace is None or not trace.get("program_spans"):
+        return None
+    by_span = trace["idle_by_span"]
+    return 100.0 * sum(by_span.get(n, 0.0) for n in names) / trace["window_s"]
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+    from bench import run
+
+    sys.exit(run.main(sys.argv[1:] + ["--trace", "1"]))

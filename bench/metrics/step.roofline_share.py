@@ -1,8 +1,7 @@
 """Sum over the traced window's steps of the least time the chip could
 take for each (the larger of operations over peak FLOP/s and bytes over
-peak bandwidth; bytes are the weights read once per step plus the live
-keys and values of each device row), over the device's busy time."""
-from bench import flops
+peak bandwidth, both counted by the configuration's family's
+``step_cost``), over the device's busy time."""
 
 
 def read(w):
@@ -13,7 +12,6 @@ def read(w):
         return None
     least = 0.0
     for s in w.steps:
-        f, b = flops.step_cost(w.model, s.device_contexts, s.host_tokens,
-                               s.prompt_tokens)
+        f, b = w.family.step_cost(w.model, s)
         least += max(f / w.peak.flops_per_s, b / w.peak.hbm_bytes_per_s)
     return 100.0 * least / busy

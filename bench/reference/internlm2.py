@@ -6,8 +6,9 @@ Per layer: ``x += Wo·attn(RoPE(Wq·n1), RoPE(Wk·n1), Wv·n1)`` and
 grouped-query causal attention, rotary embedding on the two halves of
 each head, a final RMSNorm and an untied output head.
 
-It takes its weights from ``bench.weights`` (the benchmark's own seeded
-generator, one layer at a time), never from the program under test.
+It takes its weights from the family module it is given
+(``bench/families/internlm2.py``: the benchmark's own seeded generator,
+one layer at a time), never from the program under test.
 
 ``control=True`` runs the same equations in int8 instead: weights per
 output channel and activations per token, symmetric, with int32
@@ -23,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import layer_weights, outer_weights, seed_key
+from bench.weights import seed_key
 
 F32 = jnp.float32
 
@@ -80,12 +81,12 @@ def _attention(q, k, v, length, control: bool):
     return jnp.einsum("kgts,skd->tkgd", p, v).reshape(t, h * dh)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "control"))
-def _layer(key, layer, x, lengths, *, m, control):
+@functools.partial(jax.jit, static_argnames=("m", "control", "family"))
+def _layer(key, layer, x, lengths, *, m, control, family):
     """x: (N, T, d) float32 residual stream of N sequences; ``m`` the
     model sizes as a sorted tuple of items (hashable, so static)."""
     m = dict(m)
-    w = layer_weights(key, layer, m)
+    w = family.layer_weights(key, layer, m)
     h, kvh = m["num_attention_heads"], m["num_key_value_heads"]
     eps, theta = m["rms_norm_eps"], m["rope_theta"]
     n, t, d = x.shape
@@ -111,19 +112,19 @@ def _layer(key, layer, x, lengths, *, m, control):
     return x + jax.lax.map(mlp_one, x)
 
 
-@functools.partial(jax.jit, static_argnames=("m",))
-def _embed(key, tokens, *, m):
-    return outer_weights(key, dict(m))["embed"][tokens].astype(F32)
+@functools.partial(jax.jit, static_argnames=("m", "family"))
+def _embed(key, tokens, *, m, family):
+    return family.outer_weights(key, dict(m))["embed"][tokens].astype(F32)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "control"))
-def _gaps(key, x_ref, x_ctl, tokens, *, m, control):
+@functools.partial(jax.jit, static_argnames=("m", "control", "family"))
+def _gaps(key, x_ref, x_ctl, tokens, *, m, control, family):
     """Per sequence and position, the gap by which the token judged there
     lies below the reference's best logit.  The token at position ``i+1``
     is predicted at position ``i``; with ``control`` the token judged is
     the control's own first choice instead of the one that follows."""
     m = dict(m)
-    o = outer_weights(key, m)
+    o = family.outer_weights(key, m)
     eps = m["rms_norm_eps"]
 
     def one(args):
@@ -140,16 +141,17 @@ def _gaps(key, x_ref, x_ctl, tokens, *, m, control):
     return jax.lax.map(one, (x_ref, x_ctl, tokens))
 
 
-def _hidden(key, m, tokens, lengths, control):
+def _hidden(key, m, tokens, lengths, control, family):
     mt = tuple(sorted(m.items()))
-    x = _embed(key, tokens, m=mt)
+    x = _embed(key, tokens, m=mt, family=family)
     for layer in range(m["num_hidden_layers"]):
-        x = _layer(key, jnp.int32(layer), x, lengths, m=mt, control=control)
+        x = _layer(key, jnp.int32(layer), x, lengths, m=mt, control=control,
+                   family=family)
     return x
 
 
 def logit_gaps(seed: int, m: Dict, sequences: Sequence[Tuple[List[int],
-               List[int]]], length: int, *, control: bool = False,
+               List[int]]], length: int, *, family, control: bool = False,
                host: Optional[Sequence[Sequence[bool]]] = None) -> Dict:
     """Judge each (prompt, served tokens) pair: at every served token, the
     gap (in logits) by which it lies below the reference's best token.
@@ -158,7 +160,9 @@ def logit_gaps(seed: int, m: Dict, sequences: Sequence[Tuple[List[int],
     tokens that ``host`` marks (one flag per served token: made by the
     host tier).  With ``control`` the judged tokens are those the int8
     forward puts first instead.  All sequences are padded to ``length``
-    positions, so one program serves every call."""
+    positions, so one program serves every call.  ``family`` is the
+    configuration's family module, whose ``layer_weights`` and
+    ``outer_weights`` regenerate the weights from ``seed``."""
     with jax.default_matmul_precision("highest"):
         key = seed_key(seed)
         n = len(sequences)
@@ -181,10 +185,11 @@ def logit_gaps(seed: int, m: Dict, sequences: Sequence[Tuple[List[int],
                 on_host[i, first: first + len(out)] = flags
         tok = jnp.asarray(tokens)
         lens = jnp.asarray([len(p) + len(o) for p, o in sequences], jnp.int32)
-        x_ref = _hidden(key, m, tok, lens, False)
-        x_ctl = _hidden(key, m, tok, lens, True) if control else x_ref
+        x_ref = _hidden(key, m, tok, lens, False, family)
+        x_ctl = _hidden(key, m, tok, lens, True, family) if control else x_ref
         gap = np.asarray(_gaps(key, x_ref, x_ctl, tok,
-                               m=tuple(sorted(m.items())), control=control))
+                               m=tuple(sorted(m.items())), control=control,
+                               family=family))
         widest = np.where(served, gap, -np.inf).max(axis=1)
         judged = int(served.sum())
         host_judged = int(on_host.sum())

@@ -1,7 +1,8 @@
-"""Finds a cell, its configuration, its traffic mix and its metrics by
-name, from files: ``BENCHMARK.json`` names them, and each lives in a file
-of its own under ``bench/``.  Adding a cell, a configuration, a mix or a
-metric adds files and entries and edits none."""
+"""Finds a cell, its configuration, its traffic mix, its metrics, its
+model family and its reference by name, from files: ``BENCHMARK.json``
+and the configuration file name them, and each lives in a file of its own
+under ``bench/``.  Adding a cell, a configuration (of a new architecture
+too), a mix or a metric adds files and entries and edits none."""
 from __future__ import annotations
 
 import importlib.util
@@ -11,6 +12,7 @@ from typing import Dict, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+DEFAULT_FAMILY = "internlm2"
 
 
 class Registry:
@@ -18,7 +20,8 @@ class Registry:
                  configs: Path = BENCH_DIR / "configs",
                  traffic: Path = BENCH_DIR / "traffic",
                  metrics: Path = BENCH_DIR / "metrics",
-                 references: Path = BENCH_DIR / "reference") -> None:
+                 references: Path = BENCH_DIR / "reference",
+                 families: Path = BENCH_DIR / "families") -> None:
         if not benchmark.is_file():
             raise FileNotFoundError(f"no benchmark file {benchmark}")
         self.spec = json.loads(benchmark.read_text())
@@ -26,7 +29,8 @@ class Registry:
         self.traffic = traffic
         self.metrics = metrics
         self.references = references
-        self._readers: Dict[str, object] = {}
+        self.families = families
+        self._modules: Dict[str, object] = {}
 
     def cell(self, name: str) -> Dict:
         for c in self.spec["workloads"]:
@@ -56,13 +60,28 @@ class Registry:
     def reader(self, metric: str):
         """The module ``bench/metrics/<metric>.py``; its ``read(window)``
         returns the value, or None where it finds nothing to read."""
-        if metric not in self._readers:
-            self._readers[metric] = _load(self.metrics / f"{metric}.py",
-                                          f"bench_metric_{metric}")
-        return self._readers[metric]
+        return self._module(self.metrics, metric, "bench_metric_")
 
     def reference(self, name: str):
-        return _load(self.references / f"{name}.py", f"bench_ref_{name}")
+        """The module ``bench/reference/<name>.py``: the plain reference
+        whose ``logit_gaps(...)`` judges served tokens."""
+        return self._module(self.references, name, "bench_ref_")
+
+    def family(self, name: str):
+        """The module ``bench/families/<name>.py``: the architecture's
+        ``model_config``, weights and operation counts."""
+        return self._module(self.families, name, "bench_family_")
+
+    def family_of(self, cfgfile: Dict):
+        """The family a configuration names by its ``"family"`` key; a
+        file without the key is InternLM2."""
+        return self.family(cfgfile.get("family", DEFAULT_FAMILY))
+
+    def _module(self, directory: Path, name: str, prefix: str):
+        if prefix + name not in self._modules:
+            self._modules[prefix + name] = _load(directory / f"{name}.py",
+                                                 prefix + name)
+        return self._modules[prefix + name]
 
 
 def _load(path: Path, module_name: str):

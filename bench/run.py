@@ -14,10 +14,12 @@ The run refuses to start (exit code 2, no result) unless JAX's first
 device is a TPU and there are as many as the cell asks for.
 
 Adding a cell takes files only: a configuration ``bench/configs/<name>.json``
-(model sizes as published, engine geometry, reference, correctness limits),
-a traffic mix ``bench/traffic/<mix>.json`` read by ``bench/traffic.py``, a
-reader ``bench/metrics/<metric>.py`` per new metric, and entries in
-``BENCHMARK.json``.  See ``bench/README.md``.
+(model sizes as published, engine geometry, reference, correctness limits,
+and its family where it is not InternLM2), a family module
+``bench/families/<family>.py`` and a reference ``bench/reference/<name>.py``
+for a new architecture, a traffic mix ``bench/traffic/<mix>.json`` read by
+``bench/traffic.py``, a reader ``bench/metrics/<metric>.py`` per new
+metric, and entries in ``BENCHMARK.json``.  See ``bench/README.md``.
 """
 from __future__ import annotations
 
@@ -66,8 +68,9 @@ def main(argv=None) -> int:
 
     trace_dir = harness.new_trace_dir() if args.trace else None
     try:
-        run = harness.serve_window(cfgfile, mix, args.seed, args.seconds,
-                                   t_process=T_PROCESS, trace_dir=trace_dir)
+        run = harness.serve_window(registry, cfgfile, mix, args.seed,
+                                   args.seconds, t_process=T_PROCESS,
+                                   trace_dir=trace_dir)
         w = run.window
         w.peak = peak
         if trace_dir is not None:
@@ -89,6 +92,13 @@ def main(argv=None) -> int:
           f"{run.cache_loads_in_window}; setup {w.setup_s:.2f}s")
     for line in run.notes:
         print(line)
+    if w.trace is not None:
+        print("device idle by innermost engine-thread span (s): "
+              + json.dumps(w.trace["idle_by_span"]))
+        for g in w.trace["longest_gaps"]:
+            print(f"  idle gap +{g['at_s']:.3f}s {g['s'] * 1e3:.2f} ms: "
+                  + ", ".join(f"{k} {v * 1e3:.2f}"
+                              for k, v in g["pieces"].items()))
     kind = "per_layer" if args.trace else "end_to_end"
     metrics = {}
     for entry in registry.metric_entries(cell["name"], kind):

@@ -10,24 +10,25 @@ import pytest
 from bench import harness
 from bench.registry import Registry
 from bench.traffic import Traffic
-from bench.weights import layer_weights, outer_weights, program_params, seed_key
+from bench.weights import seed_key
 
 DATA = Path(__file__).parent / "data"
 REG = Registry(DATA / "BENCHMARK.json", configs=DATA / "configs",
                traffic=DATA / "traffic")
 CFG = REG.config("tiny")
 M = CFG["model"]
+FAMILY = REG.family("internlm2")
 
 
 def test_served_weights_equal_the_reference_generator_bit_for_bit():
-    p = program_params(2**40 + 3, M)
+    p = FAMILY.program_params(2**40 + 3, M)
     key = seed_key(2**40 + 3)
     for layer in range(M["num_hidden_layers"]):
-        w = layer_weights(key, layer, M)
+        w = FAMILY.layer_weights(key, layer, M)
         assert np.array_equal(p.blocks[0]["attn"]["wq"][layer], w["wq"])
         assert np.array_equal(p.blocks[0]["ffn"]["w_down"][layer], w["w_down"])
         assert np.array_equal(p.blocks[0]["ln1"]["scale"][layer], w["ln1"])
-    o = outer_weights(key, M)
+    o = FAMILY.outer_weights(key, M)
     assert np.array_equal(p.embedding["unembed"], o["unembed"])
     assert p.blocks[0]["attn"]["wq"].dtype == jnp.bfloat16
 
@@ -42,10 +43,10 @@ def test_reference_agrees_with_the_program_forward():
     seed = 9
     rng = np.random.default_rng(0)
     prompt = [int(t) for t in rng.integers(0, M["vocab_size"], 40)]
-    mcfg = harness.model_config(CFG)
+    mcfg = FAMILY.model_config(CFG)
     import dataclasses
     mcfg = dataclasses.replace(mcfg, compute_dtype="float32")
-    params = program_params(seed, M)
+    params = FAMILY.program_params(seed, M)
     params = jax.tree.map(lambda a: a.astype(jnp.float32), params)
     out = []
     seq = list(prompt)
@@ -56,7 +57,7 @@ def test_reference_agrees_with_the_program_forward():
             nxt = int(jnp.argmax(logits[0, -1]))
             out.append(nxt)
             seq.append(nxt)
-    g = ref.logit_gaps(seed, M, [(prompt, out)], 128)
+    g = ref.logit_gaps(seed, M, [(prompt, out)], 128, family=FAMILY)
     assert g["judged"] == 12
     assert float(g["widest"].max()) < 1e-4
 
@@ -74,8 +75,8 @@ def test_control_in_int8_fails_the_limit(seed):
     from repro.serving import InferenceServer
 
     mix = REG.mix("reasoning-tiny")
-    mcfg = harness.model_config(CFG)
-    server = InferenceServer(mcfg, program_params(seed, M),
+    mcfg = FAMILY.model_config(CFG)
+    server = InferenceServer(mcfg, FAMILY.program_params(seed, M),
                              harness.server_config(CFG, mcfg))
     try:
         traffic = Traffic(mix, seed, vocab=M["vocab_size"], cache_len=128,
@@ -91,8 +92,9 @@ def test_control_in_int8_fails_the_limit(seed):
     seqs = [(r.prompt, r.output) for r in reqs]
     ref = REG.reference("internlm2")
     limit = CFG["correct"]["mean_logit_gap"]
-    prog = ref.logit_gaps(seed, M, seqs, 128, host=host)
-    ctl = ref.logit_gaps(seed, M, seqs, 128, control=True, host=host)
+    prog = ref.logit_gaps(seed, M, seqs, 128, family=FAMILY, host=host)
+    ctl = ref.logit_gaps(seed, M, seqs, 128, family=FAMILY, control=True,
+                         host=host)
     assert prog["mean"] <= limit < ctl["mean"]
     # the program's host-tier tokens pass their own limit too (at toy
     # size the control's host-tier reading is not always above it)

@@ -6,6 +6,8 @@ import sys
 import time
 from pathlib import Path
 
+import pytest
+
 from bench import harness
 from bench.registry import ROOT, Registry
 
@@ -17,8 +19,8 @@ REG = Registry(DATA / "BENCHMARK.json", configs=DATA / "configs",
 def _run(cell, seed, seconds):
     c = REG.cell(cell)
     cfg = REG.config(c["config"])
-    run = harness.serve_window(cfg, REG.mix(c["traffic"]), seed, seconds,
-                               t_process=time.perf_counter(),
+    run = harness.serve_window(REG, cfg, REG.mix(c["traffic"]), seed,
+                               seconds, t_process=time.perf_counter(),
                                log=lambda *a: None)
     return run, harness.check(REG, cfg, run.client, seed, run.failed)
 
@@ -123,3 +125,22 @@ def test_run_refuses_without_a_chip():
         timeout=120)
     assert p.returncode != 0
     assert "{" not in p.stdout
+
+
+def test_idle_by_span_command_is_a_traced_run(monkeypatch):
+    """``bench/idle_by_span.py`` forwards its arguments to ``run.py`` with
+    ``--trace 1``, and so refuses without a chip as a run does."""
+    import runpy
+
+    from bench import run
+
+    seen = []
+    monkeypatch.setattr(run, "main", lambda argv: seen.append(argv) or 2)
+    monkeypatch.setattr(sys, "argv", ["idle_by_span.py", "--workload", "c",
+                                      "--seed", "7", "--seconds", "51"])
+    with pytest.raises(SystemExit) as exit_:
+        runpy.run_path(str(ROOT / "bench" / "idle_by_span.py"),
+                       run_name="__main__")
+    assert exit_.value.code == 2
+    assert seen == [["--workload", "c", "--seed", "7", "--seconds", "51",
+                     "--trace", "1"]]

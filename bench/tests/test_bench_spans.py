@@ -1,6 +1,7 @@
 """Device idle put down to the program's own spans: the split at span
-edges on the engine's line, the readers of the host executor's
-counters, and the spans a real engine writes into a profiler trace."""
+edges on the engine's line, the layers' idle-share readers, the readers
+of the host executor's counters, and the spans a real engine writes into
+a profiler trace."""
 import pytest
 
 from bench import idle_by_span as ibs
@@ -13,6 +14,9 @@ PHASES = ("apex.admit", "apex.rebalance", "apex.tier_move", "apex.schedule",
           "apex.finish_chunks", "apex.retire")
 HOST_JOB = ("apex.host_job", "apex.host_job.device_wait", "apex.host_job.copy",
             "apex.host_job.attention")
+IDLE_SHARES = ("lifecycle.idle_share", "sched.idle_share", "engine.idle_share",
+               "host.wait_idle_share")
+REG = Registry()
 
 
 def _events():
@@ -36,7 +40,7 @@ def _events():
 
 
 def test_idle_goes_to_the_innermost_engine_span_split_at_edges():
-    r = ibs.attribute(_events())
+    r = tr.reduce(_events())
     idle = {k: v * 1e9 for k, v in r["idle_by_span"].items()}
     # gap 0-100: bench.step 0-10, admit 10-50, dispatch 50-100; gap
     # 300-500: dispatch 300-320 and 360-380, host wait 320-360, apex.step
@@ -52,39 +56,56 @@ def test_idle_goes_to_the_innermost_engine_span_split_at_edges():
 
 
 def test_idle_by_span_adds_up_to_the_window_less_busy():
-    ev = _events()
-    red = tr.reduce(ev)
-    r = ibs.attribute(ev)
-    assert sum(r["idle_by_span"].values()) == pytest.approx(
+    """The layers' idle shares, read from their own files, and the idle
+    under the benchmark's spans or none add up to the device's idle
+    share: every program span of the window belongs to one layer."""
+    red = tr.reduce(_events())
+    assert sum(red["idle_by_span"].values()) == pytest.approx(
         red["window_s"] - red["busy_s"])
-    shares = ibs.layer_shares(r["idle_by_span"], red["window_s"])
-    assert sum(shares.values()) == pytest.approx(
-        100 * (1 - red["busy_s"] / red["window_s"]))
+    w = _window({}, {})
+    w.trace = red
+    shares = {n: REG.reader(n).read(w) for n in IDLE_SHARES}
+    rest = 100 * sum(v for k, v in red["idle_by_span"].items()
+                     if not k.startswith("apex.")) / red["window_s"]
+    assert sum(shares.values()) + rest == pytest.approx(
+        REG.reader("device.idle_share").read(w))
     assert shares["lifecycle.idle_share"] == pytest.approx(4.0)
     assert shares["engine.idle_share"] == pytest.approx(10.0)
     assert shares["host.wait_idle_share"] == pytest.approx(4.0)
     assert shares["sched.idle_share"] == 0.0
-    assert shares[ibs.REST] == pytest.approx(52.0)
+    assert rest == pytest.approx(52.0)
+    # each of the engine's spans is named by one reader, none by two
+    named = [n for r in IDLE_SHARES for n in REG.reader(r).SPANS]
+    assert len(named) == len(set(named))
+    assert set(PHASES) | {"apex.step"} <= set(named)
 
 
 def test_program_spans_leave_the_window_and_busy_time_as_they_were():
     ev = _events()
     bare = dict(ev, lines=[s for s in ev["lines"] if s[0].startswith("bench.")])
-    assert tr.reduce(ev) == tr.reduce(bare)
-    r = ibs.attribute(bare)
+    full, r = tr.reduce(ev), tr.reduce(bare)
+    for k in ("busy_s", "window_s", "devices"):
+        assert full[k] == r[k]
+    assert full["breakdown"]["device_ops"] == r["breakdown"]["device_ops"]
     assert set(r["idle_by_span"]) == {"bench.step", "bench.client",
                                       "bench.wait"}
     assert sum(r["idle_by_span"].values()) == pytest.approx(
-        sum(ibs.attribute(ev)["idle_by_span"].values()))
+        sum(full["idle_by_span"].values()))
+    # without program spans, or without a trace, the idle shares have
+    # nothing to read
+    w = _window({}, {})
+    for trace in (r, None):
+        w.trace = trace
+        assert [REG.reader(n).read(w) for n in IDLE_SHARES] == [None] * 4
 
 
 def test_gaps_under_no_span_and_metadata_stripped():
     ev = _events()
     ev["spans"] = [("bench.step", 0, 400), ("bench.wait", 700, 1000)]
-    ev["lines"] = [(ibs.span_name("apex.host_job#job=3,layer=1#"), 0, 1, 0)] \
+    ev["lines"] = [(tr.span_name("apex.host_job#job=3,layer=1#"), 0, 1, 0)] \
         + [(n, a, b, 1) for n, a, b in ev["spans"]]
     assert ev["lines"][0][0] == "apex.host_job"
-    idle = ibs.attribute(ev)["idle_by_span"]
+    idle = tr.reduce(ev)["idle_by_span"]
     assert idle[ibs.NO_SPAN] == pytest.approx(200e-9)   # 400-500, 600-700
 
 
@@ -175,8 +196,8 @@ def test_engine_writes_its_spans_into_a_profiler_trace(tmp_path):
         jax.profiler.stop_trace()
         eng.shutdown()
     assert eng.stats.migrations > 0 and eng.stats.host_tokens > 0
-    ev = ibs.load(tr.find_xplane(str(tmp_path)))
-    engine = {(n, a, b) for n, a, b in ibs.engine_spans(ev)}
+    ev = tr.load(tr.find_xplane(str(tmp_path)))
+    engine = {(n, a, b) for n, a, b in ibs.engine_spans(ev["lines"])}
     names = {n for n, _, _ in engine}
     steps = [(a, b) for n, a, b in engine if n == "apex.step"]
     assert len(steps) == eng.stats.iterations

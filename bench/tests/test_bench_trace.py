@@ -27,9 +27,12 @@ def test_reduce_busy_idle_and_labels():
     assert r["window_s"] == pytest.approx(1000e-9)
     assert r["busy_s"] == pytest.approx(300e-9)     # 100-300 and 500-600
     idle = dict(r["breakdown"]["idle_gaps"])
-    # gaps: 0-100 (step), 300-500 (midpoint 400: innermost covering span),
-    # 600-1000 (midpoint 800: wait)
-    assert idle["total bench.wait"] == pytest.approx(400e-9)
+    # gaps: 0-100 (step), 300-500 (step 300-400, client 400-500), 600-1000
+    # (client 600-700, wait 700-1000), each piece under the span covering
+    # it; the longest gap is named by its largest piece
+    assert idle["total bench.wait"] == pytest.approx(300e-9)
+    assert idle["total bench.client"] == pytest.approx(200e-9)
+    assert idle["total bench.step"] == pytest.approx(200e-9)
     assert idle["longest bench.wait"] == pytest.approx(400e-9)
     assert r["breakdown"]["device_ops"] == [["jit_step", pytest.approx(300e-9)]]
 

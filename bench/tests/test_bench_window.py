@@ -77,3 +77,24 @@ def test_readers_from_files():
     # nothing traced: trace-derived readers return nothing, never 0
     assert reg.reader("device.idle_share").read(w) is None
     assert reg.reader("step.roofline_share").read(w) is None
+
+
+@pytest.mark.parametrize("metric,q", [("itl_p50_ms.device", 50),
+                                      ("itl_p99_ms.device", 99),
+                                      ("engine.itl_p95_ms", 95),
+                                      ("engine.itl_p99_ms", 99)])
+def test_device_gap_quantiles_read_only_device_gaps(metric, q):
+    # 200 device gaps (100 of 20 ms, 96 of 25 ms, 4 of 70 ms) beside
+    # host-tier gaps of a second: each reader takes its quantile of the
+    # device tier's gaps alone, in ms, and has nothing to read without them
+    dev = [0.020] * 100 + [0.025] * 96 + [0.070] * 4
+    stamps = {1: list(10.0 + np.concatenate([[0.0], np.cumsum(dev)])),
+              2: [10.0, 11.0, 12.0, 13.0]}
+    tiers = {1: ["prefill"] + ["device"] * len(dev),
+             2: ["prefill", "host", "host", "host"]}
+    reg = Registry()
+    got = reg.reader(metric).read(_window(stamps, tiers))
+    assert got == pytest.approx(1e3 * np.percentile(dev, q))
+    assert got == pytest.approx({50: 22.5, 95: 25.0, 99: 70.0}[q])
+    assert reg.reader(metric).read(_window({2: stamps[2]},
+                                           {2: tiers[2]})) is None

@@ -1,5 +1,5 @@
 """From a profiler trace to device busy time, idle share, top device
-programs and idle gaps labelled by what the host was doing.
+programs, and idle time put down to what the host was doing.
 
 The trace is JAX's ``.xplane.pb``, read with ``jax.profiler.ProfileData``
 into plain (name, start_ns, end_ns) tuples first, so that the reduction
@@ -12,11 +12,13 @@ itself is pure arithmetic and testable on recorded events.
   program each ran in.  Busy time is the union of the operations'
   intervals, averaged over the devices that ran anything.
 * Host spans are the benchmark's own ``TraceAnnotation``s (names starting
-  with ``bench.``) on the host plane.  The traced window runs from the
-  first such span's start to the last one's end.
+  with ``bench.``) and the serving program's (``apex.``) on the host
+  planes, each with the line (thread) it is on.  The traced window runs
+  from the first benchmark span's start to the last one's end.
 * An idle gap is an interval of the window with no operation on the
-  device; it is labelled by the host span that covers its midpoint, or
-  ``no span``.
+  device; ``idle_by_span.attribute`` splits it at the edges of the spans
+  on the engine's thread and credits each piece to the innermost span
+  covering it, or to ``no span``.
 """
 from __future__ import annotations
 
@@ -25,11 +27,14 @@ import os
 from collections import defaultdict
 from typing import Dict, List, Sequence, Tuple
 
+from bench import idle_by_span
+
 Interval = Tuple[str, float, float]   # (name, start_ns, end_ns)
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "apex."
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -44,19 +49,28 @@ def _is_device_plane(name: str) -> bool:
     return name.startswith("/device:TPU:")
 
 
+def span_name(name: str) -> str:
+    """A span's name without the ``#key=value#`` metadata a profiler may
+    append to it."""
+    return name.split("#", 1)[0]
+
+
 def load(path: str) -> Dict:
-    """Events of a trace file: per device its ops and modules, and the
-    host's benchmark spans."""
+    """Events of a trace file: per device its ops and modules; the host's
+    benchmark spans (``spans``); and every benchmark and program span of
+    the host planes with the line it is on, numbered in the order the
+    trace lists them (``lines``)."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
     devices: Dict[str, Dict[str, List[Interval]]] = {}
-    spans: List[Interval] = []
+    lines: List[Tuple[str, float, float, int]] = []
+    n = 0
     for plane in pd.planes:
         if _is_device_plane(plane.name):
-            lines = {line.name: line for line in plane.lines}
-            ops = lines.get(OPS_LINE)
-            mods = lines.get(MODULES_LINE)
+            named = {line.name: line for line in plane.lines}
+            ops = named.get(OPS_LINE)
+            mods = named.get(MODULES_LINE)
             devices[plane.name] = {
                 "ops": [(e.name, e.start_ns, e.end_ns) for e in ops.events]
                 if ops is not None else [],
@@ -64,9 +78,13 @@ def load(path: str) -> Dict:
                             for e in mods.events] if mods is not None else []}
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                spans += [(e.name, e.start_ns, e.end_ns) for e in line.events
-                          if e.name.startswith(SPAN_PREFIX)]
-    return {"devices": devices, "spans": spans}
+                lines += [(span_name(e.name), e.start_ns, e.end_ns, n)
+                          for e in line.events
+                          if e.name.startswith((SPAN_PREFIX, PROGRAM_PREFIX))]
+                n += 1
+    spans = [(name, a, b) for name, a, b, _ in lines
+             if name.startswith(SPAN_PREFIX)]
+    return {"devices": devices, "spans": spans, "lines": lines}
 
 
 def union(intervals: Sequence[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -95,53 +113,49 @@ def gaps(busy: Sequence[Tuple[float, float]], lo: float, hi: float):
     return out
 
 
-def label(spans: Sequence[Interval], t: float) -> str:
-    """The innermost benchmark span covering time ``t``."""
-    best = None
-    for name, a, b in spans:
-        if a <= t <= b and (best is None or b - a < best[1]):
-            best = (name, b - a)
-    return best[0] if best else "no span"
-
-
 def reduce(events: Dict, top: int = 10) -> Dict:
-    """Busy and window seconds, and the breakdown the result line carries."""
+    """Busy and window seconds, idle seconds by the innermost engine-thread
+    span (averaged over the devices that ran anything, as busy time is)
+    with the longest idle gaps, and the breakdown the result line
+    carries.  Events without ``lines`` (a recorded trace of the benchmark's
+    spans alone) count those spans as the engine's line."""
     spans = events["spans"]
     if not spans:
         raise ValueError("the trace holds no benchmark spans")
     lo = min(a for _, a, _ in spans)
     hi = max(b for _, _, b in spans)
-    busy_per_dev, gap_list = [], []
+    busy_per_dev, device_gaps = [], []
     by_module: Dict[str, float] = defaultdict(float)
-    n_dev = 0
     for dev in events["devices"].values():
         ops = clip([(a, b) for _, a, b in dev["ops"]], lo, hi)
         if not ops:
             continue
-        n_dev += 1
         merged = union(ops)
         busy_per_dev.append(sum(b - a for a, b in merged))
-        gap_list += gaps(merged, lo, hi)
+        device_gaps.append(gaps(merged, lo, hi))
         for name, a, b in dev["modules"]:
             a, b = max(a, lo), min(b, hi)
             if b > a:
                 by_module[name] += b - a
-    if not n_dev:
+    if not device_gaps:
         raise ValueError("no operation ran on a device in the traced window")
-    by_label: Dict[str, float] = defaultdict(float)
-    longest = []
-    for a, b in gap_list:
-        name = label(spans, (a + b) / 2)
-        by_label[name] += b - a
-        longest.append((b - a, name))
-    longest.sort(reverse=True)
+    lines = events.get("lines") or [(n, a, b, 0) for n, a, b in spans]
+    engine = idle_by_span.engine_spans(lines)
+    idle = idle_by_span.attribute(engine, device_gaps, lo, hi, top=top)
+    by_span = idle["idle_by_span"]
+    breakdown_idle = [[f"total {k}", v] for k, v in
+                      list(by_span.items())[: top // 2]]
+    breakdown_idle += [
+        [f"longest {max(g['pieces'], key=g['pieces'].get)}", g["s"]]
+        for g in idle["longest_gaps"][: top - len(breakdown_idle)]]
     ns = 1e-9
-    idle = [[f"total {k}", v * ns] for k, v in
-            sorted(by_label.items(), key=lambda kv: -kv[1])][: top // 2]
-    idle += [[f"longest {k}", v * ns] for v, k in longest[: top - len(idle)]]
     ops = sorted(by_module.items(), key=lambda kv: -kv[1])[:top]
-    return {"busy_s": sum(busy_per_dev) / n_dev * ns,
+    return {"busy_s": sum(busy_per_dev) / len(busy_per_dev) * ns,
             "window_s": (hi - lo) * ns,
-            "devices": n_dev,
+            "devices": len(busy_per_dev),
+            "idle_by_span": by_span,
+            "longest_gaps": idle["longest_gaps"],
+            "program_spans": sum(1 for n, _, _ in engine
+                                 if n.startswith(PROGRAM_PREFIX)),
             "breakdown": {"device_ops": [[k, v * ns] for k, v in ops],
-                          "idle_gaps": idle}}
+                          "idle_gaps": breakdown_idle}}

@@ -16,11 +16,13 @@ import numpy as np
 class StepRecord:
     """One engine step inside the window: when it ended, the live context
     of each device row that decoded in it, tokens emitted by host rows,
-    and prompt tokens prefilled in it."""
+    prompt tokens prefilled in it, and the change in it of each engine
+    counter the configuration's family names in ``STEP_COUNTERS``."""
     t_end: float
     device_contexts: List[int]
     host_tokens: int
     prompt_tokens: int
+    counters: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -38,6 +40,7 @@ class Window:
     steps: List[StepRecord]
     peak: object = None               # bench.peaks.Peak of the device
     trace: Optional[Dict] = None      # bench.trace_reduce.reduce() output
+    family: object = None             # the configuration's family module
 
     @property
     def seconds(self) -> float:
